@@ -2935,108 +2935,161 @@ struct VdsoTarget {
   uint32_t nr;
 };
 
+// Upper bound on the vDSO image this parses (Linux's and gVisor's are a
+// few pages).
+constexpr uint64_t VDSO_MAX = 1 << 20;
+
 void shim_patch_vdso() {
 #if defined(__x86_64__)
   const char* opt = getenv(ENV_VDSO);
   if (opt && strcmp(opt, "0") == 0) return;
   uintptr_t base = (uintptr_t)getauxval(AT_SYSINFO_EHDR);
   if (!base) return;  // no vDSO mapped: nothing to neutralize
+  // The ELF and program headers sit in the image's first page, which
+  // AT_SYSINFO_EHDR says is mapped; everything past them is read from a
+  // private copy (below), never through a pointer into the vDSO.
   const Elf64_Ehdr* eh = (const Elf64_Ehdr*)base;
-  if (memcmp(eh->e_ident, ELFMAG, SELFMAG) != 0) {
-    SHIM_LOG("vdso: bad ELF magic; time determinism gap remains");
+  if (memcmp(eh->e_ident, ELFMAG, SELFMAG) != 0 ||
+      eh->e_phoff + (uint64_t)eh->e_phnum * sizeof(Elf64_Phdr) > 4096) {
+    SHIM_LOG("vdso: bad ELF or program headers; time determinism gap "
+             "remains");
     return;
   }
   const Elf64_Phdr* ph = (const Elf64_Phdr*)(base + eh->e_phoff);
-  uintptr_t dyn_vaddr = 0;
-  uintptr_t load_vaddr = UINTPTR_MAX;
+  uint64_t dyn_vaddr = 0, load_vaddr = UINT64_MAX, load_end = 0;
   for (int i = 0; i < eh->e_phnum; i++) {
     if (ph[i].p_type == PT_DYNAMIC) dyn_vaddr = ph[i].p_vaddr;
-    if (ph[i].p_type == PT_LOAD && ph[i].p_vaddr < load_vaddr)
-      load_vaddr = ph[i].p_vaddr;
+    if (ph[i].p_type == PT_LOAD) {
+      if (ph[i].p_vaddr < load_vaddr) load_vaddr = ph[i].p_vaddr;
+      if (ph[i].p_vaddr + ph[i].p_memsz > load_end)
+        load_end = ph[i].p_vaddr + ph[i].p_memsz;
+    }
   }
-  if (!dyn_vaddr || load_vaddr == UINTPTR_MAX) {
+  if (!dyn_vaddr || load_vaddr == UINT64_MAX || load_end <= load_vaddr ||
+      load_end - load_vaddr > VDSO_MAX || dyn_vaddr < load_vaddr) {
     SHIM_LOG("vdso: no PT_DYNAMIC/PT_LOAD; gap remains");
     return;
   }
-  uintptr_t slide = base - load_vaddr;
-  const Elf64_Sym* symtab = nullptr;
-  const char* strtab = nullptr;
-  for (const Elf64_Dyn* d = (const Elf64_Dyn*)(slide + dyn_vaddr);
-       d->d_tag != DT_NULL; d++) {
-    uintptr_t p = (uintptr_t)d->d_un.d_ptr;
-    if (p < base) p += slide;  // vDSO d_ptr values are usually unrelocated
-    if (d->d_tag == DT_SYMTAB) symtab = (const Elf64_Sym*)p;
-    if (d->d_tag == DT_STRTAB) strtab = (const char*)p;
-  }
-  if (!symtab || !strtab || (uintptr_t)strtab <= (uintptr_t)symtab) {
-    SHIM_LOG("vdso: no dynsym/dynstr; gap remains");
-    return;
-  }
-  // .dynsym is immediately followed by .dynstr in the vDSO image; the gap
-  // between them bounds the symbol count (standard in-memory ELF trick —
-  // there is no reliable DT_HASH on all kernels).
-  size_t nsyms =
-      ((uintptr_t)strtab - (uintptr_t)symtab) / sizeof(Elf64_Sym);
-  if (nsyms == 0 || nsyms > 4096) {
-    SHIM_LOG("vdso: implausible symbol count %zu; gap remains", nsyms);
-    return;
-  }
-  const VdsoTarget targets[] = {
-      {"__vdso_clock_gettime", SYS_clock_gettime},
-      {"__vdso_gettimeofday", SYS_gettimeofday},
-      {"__vdso_time", SYS_time},
-      {"clock_gettime", SYS_clock_gettime},
-      {"gettimeofday", SYS_gettimeofday},
-      {"time", SYS_time},
-  };
+  const uint64_t image = load_end - load_vaddr;
   int memfd = (int)sys_native(SYS_open, "/proc/self/mem", O_RDWR, 0);
   if (memfd < 0) {
     SHIM_LOG("vdso: open /proc/self/mem failed: %s; gap remains",
              strerror(errno));
     return;
   }
+  // Parse a copy read through /proc/self/mem: the dynamic table, symbol
+  // table and names are located by offsets that differ between kernels
+  // (under gVisor, chasing them as pointers faulted inside this
+  // constructor and killed every managed process), so each offset is
+  // bounds-checked against the image and a bad one leaves the gap open
+  // instead of faulting.
+  uint8_t* img = (uint8_t*)sys_native(SYS_mmap, 0L, (long)image,
+                                      PROT_READ | PROT_WRITE,
+                                      MAP_PRIVATE | MAP_ANONYMOUS, -1L, 0L);
+  if (img == MAP_FAILED) {
+    sys_native(SYS_close, memfd);
+    SHIM_LOG("vdso: no memory for the image copy; gap remains");
+    return;
+  }
+  auto in_image = [&](uint64_t off, uint64_t len) {
+    return off <= image && len <= image - off;
+  };
+  // a dynamic-table address: a link-time address (the usual case) or one
+  // already relocated to `base`
+  auto ptr_off = [&](uint64_t p, uint64_t* off) {
+    if (p >= load_vaddr && p - load_vaddr < image) {
+      *off = p - load_vaddr;
+      return true;
+    }
+    if (p >= base && p - base < image) {
+      *off = p - base;
+      return true;
+    }
+    return false;
+  };
   int patched = 0, failed = 0;
-  // Track patched addresses: aliased names (clock_gettime aliases
-  // __vdso_clock_gettime) share one entry point — patch once.
-  uintptr_t done[sizeof(targets) / sizeof(targets[0])] = {0};
-  for (size_t s = 0; s < nsyms; s++) {
-    const Elf64_Sym* sym = &symtab[s];
-    if (sym->st_value == 0 || sym->st_name == 0) continue;
-    const char* nm = strtab + sym->st_name;
-    for (size_t t = 0; t < sizeof(targets) / sizeof(targets[0]); t++) {
-      if (strcmp(nm, targets[t].name) != 0) continue;
-      uintptr_t addr = slide + sym->st_value;
-      bool seen = false;
-      for (uintptr_t a : done) seen |= (a == addr);
-      if (seen) break;
-      uint32_t nr = targets[t].nr;
-      // mov eax, imm32; syscall; ret
-      uint8_t stub[8] = {0xb8, (uint8_t)nr, (uint8_t)(nr >> 8),
-                         (uint8_t)(nr >> 16), (uint8_t)(nr >> 24),
-                         0x0f, 0x05, 0xc3};
-      long w = sys_native(SYS_pwrite64, memfd, stub, sizeof(stub), addr);
-      if (w == (long)sizeof(stub) &&
-          memcmp((void*)addr, stub, sizeof(stub)) == 0) {
-        for (uintptr_t& a : done) {
-          if (a == 0) { a = addr; break; }
-        }
-        patched++;
-      } else {
-        failed++;
-        if (w != (long)sizeof(stub)) {
-          SHIM_LOG("vdso: pwrite of %s @%#lx failed (%s); gap remains", nm,
-                   (unsigned long)addr, strerror(errno));
+  uint64_t symtab = 0, strtab = 0, strsz = 0;
+  bool have_sym = false, have_str = false;
+  if (sys_native(SYS_pread64, memfd, img, (long)image, (long)base) !=
+      (long)image) {
+    SHIM_LOG("vdso: cannot read the image; gap remains");
+    goto out;
+  }
+  for (uint64_t off = dyn_vaddr - load_vaddr;
+       in_image(off, sizeof(Elf64_Dyn)); off += sizeof(Elf64_Dyn)) {
+    Elf64_Dyn d;
+    memcpy(&d, img + off, sizeof(d));
+    if (d.d_tag == DT_NULL) break;
+    if (d.d_tag == DT_SYMTAB) have_sym = ptr_off(d.d_un.d_ptr, &symtab);
+    if (d.d_tag == DT_STRTAB) have_str = ptr_off(d.d_un.d_ptr, &strtab);
+    if (d.d_tag == DT_STRSZ) strsz = d.d_un.d_val;
+  }
+  if (!have_sym || !have_str || strtab <= symtab) {
+    SHIM_LOG("vdso: no dynsym/dynstr; gap remains");
+    goto out;
+  }
+  if (!strsz || !in_image(strtab, strsz)) strsz = image - strtab;
+  {
+    // .dynsym is immediately followed by .dynstr in the vDSO image; the
+    // gap between them bounds the symbol count (standard in-memory ELF
+    // trick — there is no reliable DT_HASH on all kernels).
+    const uint64_t nsyms = (strtab - symtab) / sizeof(Elf64_Sym);
+    const VdsoTarget targets[] = {
+        {"__vdso_clock_gettime", SYS_clock_gettime},
+        {"__vdso_gettimeofday", SYS_gettimeofday},
+        {"__vdso_time", SYS_time},
+        {"clock_gettime", SYS_clock_gettime},
+        {"gettimeofday", SYS_gettimeofday},
+        {"time", SYS_time},
+    };
+    // Track patched addresses: aliased names (clock_gettime aliases
+    // __vdso_clock_gettime) share one entry point — patch once.
+    uint64_t done[sizeof(targets) / sizeof(targets[0])] = {0};
+    for (uint64_t s = 0; s < nsyms; s++) {
+      Elf64_Sym sym;
+      memcpy(&sym, img + symtab + s * sizeof(sym), sizeof(sym));
+      if (sym.st_value == 0 || sym.st_name == 0 || sym.st_name >= strsz)
+        continue;
+      const char* nm = (const char*)img + strtab + sym.st_name;
+      const size_t nm_len = strnlen(nm, strsz - sym.st_name);
+      uint64_t off;
+      if (nm_len == strsz - sym.st_name || !ptr_off(sym.st_value, &off) ||
+          !in_image(off, 8))
+        continue;
+      for (size_t t = 0; t < sizeof(targets) / sizeof(targets[0]); t++) {
+        if (strcmp(nm, targets[t].name) != 0) continue;
+        uintptr_t addr = base + off;
+        bool seen = false;
+        for (uint64_t a : done) seen |= (a == addr);
+        if (seen) break;
+        uint32_t nr = targets[t].nr;
+        // mov eax, imm32; syscall; ret
+        uint8_t stub[8] = {0xb8, (uint8_t)nr, (uint8_t)(nr >> 8),
+                           (uint8_t)(nr >> 16), (uint8_t)(nr >> 24),
+                           0x0f, 0x05, 0xc3};
+        uint8_t back[8] = {0};
+        long w = sys_native(SYS_pwrite64, memfd, stub, sizeof(stub), addr);
+        long r = sys_native(SYS_pread64, memfd, back, sizeof(back), addr);
+        if (w == (long)sizeof(stub) && r == (long)sizeof(back) &&
+            memcmp(back, stub, sizeof(stub)) == 0) {
+          for (uint64_t& a : done) {
+            if (a == 0) { a = addr; break; }
+          }
+          patched++;
         } else {
-          SHIM_LOG("vdso: write to %s @%#lx did not take (readback "
-                   "mismatch); gap remains", nm, (unsigned long)addr);
+          failed++;
+          SHIM_LOG("vdso: patching %s @%#lx did not take (write %ld, "
+                   "read %ld); gap remains", nm, (unsigned long)addr, w, r);
         }
+        break;
       }
-      break;
     }
   }
-  sys_native(SYS_close, memfd);
   SHIM_LOG("vdso: neutralized %d time entry points (%d failed)", patched,
            failed);
+out:
+  sys_native(SYS_munmap, img, (long)image);
+  sys_native(SYS_close, memfd);
 #endif
 }
 

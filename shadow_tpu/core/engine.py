@@ -230,8 +230,8 @@ class _DenseWindow(NamedTuple):
 class _Tail(NamedTuple):
     """Rows not extracted into the dense matrix: out-of-window events,
     per-host deferred leftovers (rank >= Kc), and spent filler rows (time
-    NEVER). Flat [N - H*Kc] arrays; payload is a list of P word columns so
-    it can ride merge sorts as operands."""
+    NEVER). Flat [N - H*Kc] arrays; payload is a list of packed word
+    columns, as the merge takes them."""
 
     time: jnp.ndarray
     src: jnp.ndarray
@@ -245,6 +245,29 @@ class _Tail(NamedTuple):
 # bounds a single window's span (runahead), far beyond any real runahead
 _DT_BITS = 44
 _DT_MAX = (1 << _DT_BITS) - 1
+_LO32 = 0xFFFFFFFF
+
+
+def _pack2(hi, lo):
+    """Two int32 columns in one int64 (hi in the top half)."""
+    return (hi.astype(jnp.int64) << 32) | (lo.astype(jnp.int64) & _LO32)
+
+
+def _unpack2(x):
+    return (x >> 32).astype(jnp.int32), x.astype(jnp.int32)
+
+
+def _take_rows(cols, perm):
+    """Gather the int64 row columns `cols` through `perm` as ONE whole-row
+    gather of their [N, len(cols)] stack; returns the columns.
+
+    The sorts below carry only their keys and a row index: TPU compile
+    time grows with a sort's operand count (described v5e, PR 21: ~50 s
+    per int64 operand at 165k rows; the 11-operand window sorts took the
+    udp_flood_10k program to ~19 min), so every payload column follows
+    the sort's permutation here instead of riding it as an operand."""
+    rows = jnp.take(jnp.stack(cols, axis=1), perm, axis=0, mode="clip")
+    return [rows[:, i] for i in range(len(cols))]
 
 
 class IslandSpec(NamedTuple):
@@ -327,20 +350,19 @@ def _island_route(
     k1_r = (group << _DT_BITS) | dt
     gf = jnp.repeat(jnp.arange(S, dtype=jnp.int64), X)
     k1_f = (gf << _DT_BITS) | _DT_MAX
-    z32 = jnp.zeros((SX,), jnp.int32)
-    cat = [
-        jnp.concatenate([k1_r, k1_f]),
+    z64 = jnp.zeros((SX,), jnp.int64)
+    n_r = k1_r.shape[0]
+    cat_cols = [
         jnp.concatenate([m_t, jnp.full((SX,), NEVER, jnp.int64)]),
-        jnp.concatenate([m_d, z32]),
-        jnp.concatenate([m_s, z32]),
-        jnp.concatenate([m_q, z32]),
-        jnp.concatenate([m_k, z32]),
-    ] + [jnp.concatenate([p, jnp.zeros((SX,), jnp.int64)]) for p in m_p]
-    ops = jax.lax.sort(cat, num_keys=1, is_stable=True)
-    s_k1 = ops[0]
-    N = s_k1.shape[0]
-    s_group = s_k1 >> _DT_BITS
+        jnp.concatenate([_pack2(m_d, m_k), z64]),
+        jnp.concatenate([_pack2(m_s, m_q), z64]),
+    ] + [jnp.concatenate([p, z64]) for p in m_p]
+    N = n_r + SX
     iota = jnp.arange(N, dtype=jnp.int32)
+    s_k1, perm1 = jax.lax.sort(
+        [jnp.concatenate([k1_r, k1_f]), iota], num_keys=2
+    )
+    s_group = s_k1 >> _DT_BITS
     boundary = jnp.concatenate(
         [jnp.ones((1,), bool), s_group[1:] != s_group[:-1]]
     )
@@ -352,9 +374,14 @@ def _island_route(
     extract = (s_group < S) & (rank < X)
     slot = jnp.where(extract, (s_group * X + rank.astype(jnp.int64)), SX)
     k2 = (slot << _DT_BITS) | (s_k1 & _DT_MAX)
-    ops2 = jax.lax.sort([k2] + list(ops[1:]), num_keys=1, is_stable=True)
-    cols = ops2[1:]  # t, d, s, q, k, p...
-    sent = jnp.sum(extract & (ops[1] != NEVER), dtype=jnp.int64)
+    # ties in k2 keep the first sort's order (iota), as a stable sort would
+    _, _, perm = jax.lax.sort([k2, iota, perm1], num_keys=2)
+    g_t, g_dk, g_sq, *g_p = _take_rows(cat_cols, perm)
+    g_d, g_k = _unpack2(g_dk)
+    g_s, g_q = _unpack2(g_sq)
+    cols = [g_t, g_d, g_s, g_q, g_k] + g_p
+    # filler rows (cat index >= n_r) carry time NEVER: not sent
+    sent = jnp.sum(extract & (perm1 < n_r), dtype=jnp.int64)
 
     recv_cols = []
     for c in cols:
@@ -387,10 +414,11 @@ def _island_route(
 
 def _dense_extract(pool: EventPool, win_start, win_end, H: int, Kc: int,
                    PP: int, lrow=None):
-    """Extract the window into a dense [H, Kc] matrix with SORTS AND SCANS
-    ONLY (profiled on v5e: large gathers serialize at ~9 ns/element while
-    multi-operand sorts run near memory bandwidth — so every event column
-    and payload word rides the sorts as an operand).
+    """Extract the window into a dense [H, Kc] matrix with two key sorts,
+    a scan and ONE whole-row gather. The sorts carry only keys and a row
+    index; the event columns and payload words follow the final
+    permutation in the gather (_take_rows: an operand per column made the
+    TPU compile of these sorts take tens of minutes).
 
     Sort cost on TPU scales with rows × comparator stages (measured:
     payload-operand packing barely moved it, key count does), so the
@@ -438,20 +466,18 @@ def _dense_extract(pool: EventPool, win_start, win_end, H: int, Kc: int,
     k1_f = (key_f.astype(jnp.int64) << _DT_BITS) | _DT_MAX
     cat_k1 = jnp.concatenate([k1_r, k1_f])
     cat_k2 = jnp.concatenate([k2_r, jnp.zeros((HK,), jnp.int64)])
-    cat_t = jnp.concatenate([pool.time, jnp.full((HK,), NEVER, jnp.int64)])
-    zf = jnp.zeros((HK,), jnp.int32)
-    cat_d = jnp.concatenate([pool.dst, key_f])  # TRUE dst rides along
-    cat_k = jnp.concatenate([pool.kind, zf])
     zf64 = jnp.zeros((HK,), jnp.int64)
-    pcols = [jnp.concatenate([pool.payload[:, w], zf64]) for w in range(PP)]
-    ops = jax.lax.sort(
-        [cat_k1, cat_k2, cat_t, cat_k, cat_d] + pcols,
-        num_keys=2, is_stable=True,
-    )
-    s_k1, s_k2, s_t, s_k, s_d = ops[:5]
-    s_p = ops[5:]
-    s_key = (s_k1 >> _DT_BITS).astype(jnp.int32)
+    cat_cols = [
+        jnp.concatenate([pool.time, jnp.full((HK,), NEVER, jnp.int64)]),
+        cat_k2,
+        # TRUE dst rides along (fillers carry their host id)
+        jnp.concatenate([_pack2(pool.dst, pool.kind),
+                         _pack2(key_f, jnp.zeros((HK,), jnp.int32))]),
+    ] + [jnp.concatenate([pool.payload[:, w], zf64]) for w in range(PP)]
     iota = jnp.arange(N, dtype=jnp.int32)
+    # the row index as the last key orders ties as a stable sort would
+    s_k1, _, perm1 = jax.lax.sort([cat_k1, cat_k2, iota], num_keys=3)
+    s_key = (s_k1 >> _DT_BITS).astype(jnp.int32)
     boundary = jnp.concatenate(
         [jnp.ones((1,), bool), s_key[1:] != s_key[:-1]]
     )
@@ -459,23 +485,22 @@ def _dense_extract(pool: EventPool, win_start, win_end, H: int, Kc: int,
     rank = iota - run_start
     extract = (s_key < H) & (rank < Kc)
     slot = jnp.where(extract, s_key * Kc + rank, jnp.int32(N))
-    ops2 = jax.lax.sort(
-        [slot, s_t, s_k2, s_k, s_d] + list(s_p),
-        num_keys=1, is_stable=True,
-    )
-    o_t, o_k2, o_k, o_d = ops2[1], ops2[2], ops2[3], ops2[4]
+    # slot, then the first sort's order: one packed key (both < 2^31)
+    _, perm = jax.lax.sort([_pack2(slot, iota), perm1], num_keys=1)
+    o_t, o_k2, o_dk, *o_p = _take_rows(cat_cols, perm)
+    o_d, o_k = _unpack2(o_dk)
     o_s = (o_k2 >> 32).astype(jnp.int32)
     o_q = o_k2.astype(jnp.int32)  # low 32 bits (seq is nonnegative)
     d_t = o_t[:HK].reshape(H, Kc)
     d_s = o_s[:HK].reshape(H, Kc)
     d_q = o_q[:HK].reshape(H, Kc)
     d_k = o_k[:HK].reshape(H, Kc)
-    d_p = jnp.stack([o[:HK].reshape(H, Kc) for o in ops2[5:]], axis=-1)
+    d_p = jnp.stack([o[:HK].reshape(H, Kc) for o in o_p], axis=-1)
     dense = _DenseWindow(time=d_t, src=d_s, seq=d_q, kind=d_k, payload=d_p)
     tail = _Tail(
         time=o_t[HK:], src=o_s[HK:], seq=o_q[HK:],
         kind=o_k[HK:], dst=o_d[HK:],
-        payload=[o[HK:] for o in ops2[5:]],
+        payload=[o[HK:] for o in o_p],
     )
     return dense, tail
 
@@ -707,15 +732,18 @@ def make_window_step(
             C = state.pool.capacity
             arrival_min = jnp.asarray(NEVER, jnp.int64)
             if island is None:
-                ops3 = jax.lax.sort(
-                    [m_t, m_d, m_s, m_q, m_k] + m_p, num_keys=1,
-                    is_stable=True,
+                iota = jnp.arange(m_t.shape[0], dtype=jnp.int32)
+                s_t, perm = jax.lax.sort([m_t, iota], num_keys=2)
+                dropped = jnp.sum(s_t[C:] != NEVER, dtype=jnp.int64)
+                perm = perm[:C]
+                g_dk, g_sq, *g_p = _take_rows(
+                    [_pack2(m_d, m_k), _pack2(m_s, m_q)] + list(m_p), perm
                 )
-                dropped = jnp.sum(ops3[0][C:] != NEVER, dtype=jnp.int64)
+                g_d, g_k = _unpack2(g_dk)
+                g_s, g_q = _unpack2(g_sq)
                 new_pool = EventPool(
-                    time=ops3[0][:C], dst=ops3[1][:C], src=ops3[2][:C],
-                    seq=ops3[3][:C], kind=ops3[4][:C],
-                    payload=jnp.stack([o[:C] for o in ops3[5:]], axis=-1),
+                    time=s_t[:C], dst=g_d, src=g_s, seq=g_q, kind=g_k,
+                    payload=jnp.stack(g_p, axis=-1),
                 )
                 return state.replace(
                     pool=new_pool,
@@ -1395,14 +1423,11 @@ def make_window_step(
 
                 def _never(_):
                     never = jnp.asarray(NEVER, jnp.int64)
-                    pcast = getattr(jax.lax, "pcast", None)
-                    if island is not None and pcast is not None:
+                    if island is not None:
                         # under shard_map the true branch's output varies
                         # over the islands axis; the constant must be cast
                         # to the same varying type or cond rejects it
-                        # (jax < 0.7 has no varying-type checker and no
-                        # lax.pcast — the bare constant is already valid)
-                        never = pcast(
+                        never = jax.lax.pcast(
                             never, (island.axis,), to="varying"
                         )
                     return never

@@ -1,6 +1,6 @@
 """Occupancy-adaptive pool gearing: tiered window kernels.
 
-The window kernel's dominant cost is its multi-operand stable sorts
+The window kernel's dominant cost is its sorts and their row gathers
 (_dense_extract and the merge, core/engine.py), and sort cost on TPU
 scales with rows × comparator stages. Pool capacity C is a STATIC shape
 compiled into the kernel, sized for the burst worst case — but PHOLD-class

@@ -85,10 +85,10 @@ class PoolExhausted(RuntimeError):
 # ---------------------------------------------------------------------------
 #
 # The window kernel's peak working set is the live state plus the sort
-# temporaries: XLA's multi-operand stable sorts materialize an output copy
-# of every operand, and the dense-window extraction concatenates pool +
-# filler rows before sorting, so the transient peak is a small multiple of
-# the pool + dense bytes. The factor below is deliberately conservative
+# temporaries: the key sorts and the row gathers that follow them
+# materialize a copy of every column, and the dense-window extraction
+# concatenates pool + filler rows before sorting, so the transient peak is
+# a small multiple of the pool + dense bytes. The factor below is deliberately conservative
 # (an over-estimate sheds a sweep the device could maybe have served; an
 # under-estimate OOMs it mid-run).
 

@@ -64,11 +64,11 @@ def pack_words(payload):
     """[..., P] i32 payload words → [..., ceil(P/2)] i64, pairs packed as
     (hi word 2w+1) << 32 | (lo word 2w, zero-extended).
 
-    The engine's sorts carry every payload word as an operand; packing
-    halves the operand count (and the box-write traffic) at the cost of
-    one elementwise pass at the pack/unpack boundaries — profiled on v5e,
-    the sorts dominate the window step at netstack shapes, so this is a
-    direct win. Odd P pads the last high word with zero."""
+    Every payload word is a column of the engine's window-sort
+    permutation gathers (engine._take_rows); packing halves the column
+    count (and the box-write traffic) at the cost of one elementwise pass
+    at the pack/unpack boundaries. Odd P pads the last high word with
+    zero."""
     P = payload.shape[-1]
     if P % 2:
         payload = jnp.concatenate(

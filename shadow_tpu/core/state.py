@@ -53,9 +53,9 @@ class EventPool:
     like the reference's per-source event ID.
 
     Payload words are stored PACKED, two i32 words per i64 column
-    (core.soa.pack_words): every payload column rides the engine's window
-    sorts as an operand, and packing halves that operand count — the sorts
-    are the dominant window cost at netstack shapes (profiled on v5e).
+    (core.soa.pack_words): every payload column follows the engine's
+    window sorts through a row gather, and packing halves that column
+    count.
     Handlers always see the unpacked [H, P] i32 view via EventView.
     """
 

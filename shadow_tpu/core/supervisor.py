@@ -75,7 +75,7 @@ RESOURCE_EXHAUSTED = "resource_exhausted"
 FATAL = "fatal"
 
 # Substrings (lowercased) that mark a dispatch error as a dead/unreachable
-# backend: the PJRT client's UNAVAILABLE family, tunnel/worker drops, and
+# backend: the PJRT client's UNAVAILABLE family, dropped connections, and
 # the runtime watchdog's own verdicts. Deliberately conservative — an
 # unrecognized error stays FATAL and propagates (misclassifying a real bug
 # as backend loss would send the supervisor into a pointless drain loop).
@@ -88,12 +88,21 @@ _LOST_MARKERS = (
     "socket closed",
     "broken pipe",
     "device lost",
-    "device or resource busy",
-    "initialize backend",
     "core halted",
     "tpu driver",
     "worker exited",
     "heartbeat timeout",
+)
+
+# A chip held by another process: on a locally attached chip the backend
+# cannot start ("Unable to initialize backend 'tpu'", libtpu's "device or
+# resource busy" on its lock file). No wait brings it back while that
+# process lives, so these fail fast (FATAL), ahead of every other table —
+# the same text often carries an UNAVAILABLE that would read as a loss.
+_HELD_MARKERS = (
+    "device or resource busy",
+    "initialize backend",
+    "already in use",
 )
 
 # Mesh-collective failure markers: a cross-chip collective (the async
@@ -174,8 +183,8 @@ class ChipLost(BackendLost):
 
 def classify_failure(exc: BaseException) -> str:
     """TRANSIENT (bounded retry), RESOURCE_EXHAUSTED (pressure ladder),
-    BACKEND_LOST (drain + policy), or FATAL (re-raise: a real bug, not an
-    infrastructure failure). Mesh-collective failures (`chip_scoped`)
+    BACKEND_LOST (drain + policy), or FATAL (re-raise: a real bug, or a
+    chip another process holds). Mesh-collective failures (`chip_scoped`)
     classify BACKEND_LOST — checked before the transient table, so a
     dead ppermute peer is never retried forever."""
     if isinstance(exc, BackendLost):
@@ -183,6 +192,8 @@ def classify_failure(exc: BaseException) -> str:
     if isinstance(exc, PoolExhausted):
         return RESOURCE_EXHAUSTED
     msg = f"{type(exc).__name__}: {exc}".lower()
+    if any(marker in msg for marker in _HELD_MARKERS):
+        return FATAL
     for marker in _EXHAUSTED_MARKERS:
         if marker in msg:
             return RESOURCE_EXHAUSTED
@@ -214,9 +225,8 @@ def probe_backend() -> bool:
     """One trivial dispatch against the default backend — the shared
     liveness probe: the supervisor's SUSPECT→LOST check and the serve
     daemon's `/healthz` both use it, so a probe verdict means the same
-    thing everywhere. Real deployments that fear a HANGING (not erroring)
-    backend should pass a subprocess prober (bench.wait_for_backend is
-    one); in-process keeps the library dependency-free."""
+    thing everywhere. In-process on purpose: a child process could not
+    reach a chip this process holds."""
     try:
         import jax.numpy as jnp
 

@@ -1,9 +1,12 @@
-"""The flagship on-device workload: PHOLD over a 50ms self-loop link.
+"""The flagship on-device workloads.
 
-This is the reference's PDES canary (src/test/phold/phold.yaml: peers on a
-single-vertex self-loop graph exchanging random-destination messages) scaled
-to arbitrary host counts. Shared by bench.py and __graft_entry__.py so the
-benchmark and the driver's compile checks always exercise the same model.
+PHOLD over a 50ms self-loop link is the reference's PDES canary
+(src/test/phold/phold.yaml: peers on a single-vertex self-loop graph
+exchanging random-destination messages) scaled to arbitrary host counts.
+The UDP flood is BASELINE config 2: clients flooding servers through the
+full device network stack (NIC token buckets, CoDel router, UDP sockets).
+Shared by bench.py, chip_smoke.py and __graft_entry__.py so the benchmark,
+the chip smoke and the compile checks always exercise the same models.
 """
 
 from __future__ import annotations
@@ -103,4 +106,70 @@ def build_phold_flagship(
                 }
             },
         }
+    )
+
+
+def stack_config(
+    app_model: str,
+    num_hosts: int,
+    *,
+    loss: float,
+    app_options: dict,
+    stop_s: int = 4,
+    seed: int = 7,
+    event_capacity: int = 1 << 15,
+    experimental: dict | None = None,
+) -> dict:
+    """Config dict of a network-stack workload: one server per eight
+    hosts, the rest clients with `app_options`, every host on one 1 Gbit
+    vertex over a 10 ms self-loop with `loss`."""
+    n_servers = num_hosts // 8
+    return {
+        "general": {"stop_time": stop_s, "seed": seed},
+        "network": {"graph": {"type": "gml", "inline": (
+            'graph [\n'
+            '  node [ id 0 bandwidth_down "1 Gbit" bandwidth_up "1 Gbit" ]\n'
+            f'  edge [ source 0 target 0 latency "10 ms" packet_loss {loss} ]\n'
+            ']\n')}},
+        # Pool capacity sized to the workload's in-flight population
+        # (timers + packets in transit): the per-window pool sort scales
+        # with capacity, so oversizing it is pure waste.
+        "experimental": {
+            "event_capacity": event_capacity,
+            "events_per_host_per_window": 16,
+            "outbox_slots": 16,
+            # every ring/inbox slot is a full [H, slots, P] write per
+            # update, so these are sized to the queue depths, no further
+            "router_queue_slots": 16,
+            "inbox_slots": 4,
+            **(experimental or {}),
+        },
+        "hosts": {
+            "server": {"quantity": n_servers, "app_model": app_model,
+                       "app_options": {"role": "server"}},
+            "client": {"quantity": num_hosts - n_servers,
+                       "app_model": app_model, "app_options": app_options},
+        },
+    }
+
+
+def udp_flood_config(num_hosts: int, stop_s: int = 4, **experimental) -> dict:
+    """BASELINE config 2 at `num_hosts`: 1 KiB datagrams every 20 ms per
+    client. K/O/C are sized to the workload's Poisson tails: 1 << 14 pool
+    rows overflow at 10k hosts, 1 << 15 do not."""
+    return stack_config(
+        "udp_flood", num_hosts, loss=0.001,
+        app_options={"interval": "20 ms", "size": 1024,
+                     "runtime": stop_s - 1},
+        stop_s=stop_s,
+        experimental={"events_per_host_per_window": 12, "outbox_slots": 8,
+                      **experimental},
+    )
+
+
+def build_udp_flood(num_hosts: int, stop_s: int = 4, **experimental):
+    from shadow_tpu.sim import build_simulation
+
+    return build_simulation(
+        udp_flood_config(num_hosts, stop_s=stop_s, **experimental)
     )

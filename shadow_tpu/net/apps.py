@@ -328,11 +328,8 @@ class UdpFloodApp:
                 self.num_hosts, self.server_hosts, self.local_span
             )
         )
-        for s in self.server_hosts:
-            stack.bind_udp(s, 0, SERVER_PORT)
-        for h in range(self.num_hosts):
-            if role[h] == 1:
-                stack.bind_udp(h, 0, CLIENT_PORT_BASE)
+        stack.bind_udp(np.flatnonzero(role == 0), 0, SERVER_PORT)
+        stack.bind_udp(np.flatnonzero(role == 1), 0, CLIENT_PORT_BASE)
 
     def init_sub(self) -> dict:
         H = self.num_hosts
@@ -416,9 +413,7 @@ class UdpEchoApp:
         role[self.server_host] = 0
         self._role = jnp.asarray(role)
         stack.bind_udp(self.server_host, 0, SERVER_PORT)
-        for h in range(self.num_hosts):
-            if h != self.server_host:
-                stack.bind_udp(h, 0, CLIENT_PORT_BASE)
+        stack.bind_udp(np.flatnonzero(role == 1), 0, CLIENT_PORT_BASE)
 
     def init_sub(self) -> dict:
         H = self.num_hosts

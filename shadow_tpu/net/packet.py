@@ -125,16 +125,27 @@ def unpack_time(payload):
 def make_udp(src_port, dst_port, length, priority, src_host, socket_slot=None,
              payload_words: int = PAYLOAD_WORDS):
     """Assemble [H, P] payload words for a UDP datagram (vectorized)."""
-    H = src_port.shape[0]
-    pl = jnp.zeros((H, payload_words), dtype=jnp.int32)
+    words = {
+        W_PROTO: PROTO_UDP, W_SRC_PORT: src_port, W_DST_PORT: dst_port,
+        W_LEN: length, W_PRIORITY: priority, W_SRC_HOST: src_host,
+    }
     if payload_words > W_TRAIL:
-        pl = pl.at[:, W_TRAIL].set(PDS_CREATED)
-    pl = pl.at[:, W_PROTO].set(PROTO_UDP)
-    pl = pl.at[:, W_SRC_PORT].set(src_port.astype(jnp.int32))
-    pl = pl.at[:, W_DST_PORT].set(dst_port.astype(jnp.int32))
-    pl = pl.at[:, W_LEN].set(length.astype(jnp.int32))
-    pl = pl.at[:, W_PRIORITY].set(priority.astype(jnp.int32))
-    pl = pl.at[:, W_SRC_HOST].set(src_host.astype(jnp.int32))
+        words[W_TRAIL] = PDS_CREATED
     if socket_slot is not None:
-        pl = pl.at[:, W_SOCKET].set(socket_slot.astype(jnp.int32))
-    return pl
+        words[W_SOCKET] = socket_slot
+    return stack_words(words, src_port.shape[0], payload_words)
+
+
+def stack_words(words: dict, H: int, payload_words: int):
+    """[H, payload_words] int32 payload from {word index: [H] or scalar},
+    zero elsewhere — built by stacking columns, not by per-column .at[]
+    updates, which lower to scatters."""
+    zero = jnp.zeros((H,), jnp.int32)
+    return jnp.stack(
+        [
+            jnp.broadcast_to(jnp.asarray(words[w], jnp.int32), (H,))
+            if w in words else zero
+            for w in range(payload_words)
+        ],
+        axis=1,
+    )

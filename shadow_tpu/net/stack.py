@@ -112,8 +112,9 @@ class NetStack:
 
     # ---- build-time API ----
 
-    def bind_udp(self, host: int, slot: int, port: int, peer_host: int = udp.ANY_PEER,
+    def bind_udp(self, host, slot: int, port: int, peer_host: int = udp.ANY_PEER,
                  peer_port: int = 0):
+        """Bind `slot` on one host, or on an array of hosts alike."""
         self._init_udp = udp.bind_static(
             self._init_udp, host, slot, port, peer_host, peer_port
         )

@@ -221,7 +221,7 @@ class MetricsRegistry:
 
 def dump_json_atomic(path: str, doc: dict, indent: int | None = 1) -> None:
     """tmp + fsync + rename, the checkpoint plane's torn-write discipline
-    (core/checkpoint.py): a poller, tpu_watch, or perf_compare reading
+    (core/checkpoint.py): a poller or perf_compare reading
     `path` concurrently sees either the previous complete document or
     this one — never a truncated JSON."""
     import os

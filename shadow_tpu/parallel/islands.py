@@ -60,6 +60,46 @@ AXIS = "islands"
 _MAX_SUBSTEPS = 4096
 
 
+_SIGN32 = np.int32(-(1 << 31))
+
+
+def _split64(x):
+    """int64 -> (high word, low word biased so signed order is unsigned
+    order), both int32."""
+    return (x >> 32).astype(jnp.int32), x.astype(jnp.int32) ^ _SIGN32
+
+
+def _join64(hi, lo):
+    return (hi.astype(jnp.int64) << 32) | (
+        (lo ^ _SIGN32).astype(jnp.int64) & 0xFFFFFFFF
+    )
+
+
+def pmin(x, axis):
+    """lax.pmin that the TPU lowers for int64 too: a 64-bit all-reduce
+    lowers only as a sum there (the described v5e:2x2 compile refused the
+    mesh kernel's int64 pmin, PR 21), so an int64 min is two int32
+    all-reduces — the high words, then the low words of the shards that
+    hold the min high word. Exact; other dtypes pass straight through."""
+    if x.dtype != jnp.int64:
+        return jax.lax.pmin(x, axis)
+    hi, lo = _split64(x)
+    m_hi = jax.lax.pmin(hi, axis)
+    m_lo = jax.lax.pmin(jnp.where(hi == m_hi, lo, np.int32(2**31 - 1)),
+                        axis)
+    return _join64(m_hi, m_lo)
+
+
+def pmax(x, axis):
+    """lax.pmax counterpart of `pmin` (int64 as two int32 all-reduces)."""
+    if x.dtype != jnp.int64:
+        return jax.lax.pmax(x, axis)
+    hi, lo = _split64(x)
+    m_hi = jax.lax.pmax(hi, axis)
+    m_lo = jax.lax.pmax(jnp.where(hi == m_hi, lo, _SIGN32), axis)
+    return _join64(m_hi, m_lo)
+
+
 # ---------------------------------------------------------------------------
 # Shard kernel factories
 # ---------------------------------------------------------------------------
@@ -79,13 +119,13 @@ def make_shard_run_to(step, hi: int, axis: str = AXIS):
 
     def step_shard(state, params, ws, we):
         st, mn = step(state, params, ws, we)
-        return st, jax.lax.pmin(mn, axis)
+        return st, pmin(mn, axis)
 
     def _occ(state):
         return jnp.sum(state.pool.time != simtime.NEVER)
 
     def _press(state):
-        return jax.lax.pmax((_occ(state) >= hi).astype(jnp.int32), axis)
+        return pmax((_occ(state) >= hi).astype(jnp.int32), axis)
 
     def run_to(state, params, runahead, stop, max_windows):
         runahead = jnp.asarray(runahead, jnp.int64)
@@ -101,18 +141,18 @@ def make_shard_run_to(step, hi: int, axis: str = AXIS):
             ws = mn
             # exchange-backpressure clamp: never let any shard process
             # past an event still in transit (deferred exchange)
-            clamp = jax.lax.pmin(state.exch_deferred_min, axis)
+            clamp = pmin(state.exch_deferred_min, axis)
             we = jnp.minimum(jnp.minimum(ws + runahead, stop), clamp)
             state, mn = step_shard(state, params, ws, we)
             return state, mn, w + 1
 
-        mn0 = jax.lax.pmin(jnp.min(state.pool.time), axis)
+        mn0 = pmin(jnp.min(state.pool.time), axis)
         state, mn, w = jax.lax.while_loop(
             cond, body, (state, mn0, jnp.int32(0))
         )
         # occupancy rides back pmax'd: the gearing decision covers the
         # FULLEST shard (every shard's pool compiles the same capacity)
-        occ = jax.lax.pmax(_occ(state), axis)
+        occ = pmax(_occ(state), axis)
         return state, mn, _press(state) > 0, occ, w
 
     return run_to
@@ -198,7 +238,7 @@ def make_shard_run_to_async(step, hi: int, axis: str = AXIS,
         return jnp.sum(state.pool.time != simtime.NEVER)
 
     def _press(state):
-        return jax.lax.pmax((_occ(state) >= hi).astype(jnp.int32), axis)
+        return pmax((_occ(state) >= hi).astype(jnp.int32), axis)
 
     def run_to(state, params, runahead, look_in, spread, stop, max_windows):
         runahead = jnp.asarray(runahead, jnp.int64)
@@ -243,22 +283,22 @@ def make_shard_run_to_async(step, hi: int, axis: str = AXIS,
 
         def _horizon(frontier, state):
             bound = _bound(frontier)
-            defer = jax.lax.pmin(state.exch_deferred_min, axis)
+            defer = pmin(state.exch_deferred_min, axis)
             return jnp.minimum(jnp.minimum(bound, defer), stop)
 
         def cond(c):
             state, frontier, mn, w, _ = c
-            live = jax.lax.pmin(frontier, axis) < stop
+            live = pmin(frontier, axis) < stop
             return live & (w < max_windows) & (_press(state) == 0)
 
         def body(c):
             state, frontier, mn, w, stats = c
             spread_max, steps, yields, blocked = stats
             hz = _horizon(frontier, state)
-            minF = jax.lax.pmin(frontier, axis)
-            maxF = jax.lax.pmax(frontier, axis)
+            minF = pmin(frontier, axis)
+            maxF = pmax(frontier, axis)
             spread_max = jnp.maximum(spread_max, maxF - minF)
-            mn_all = jax.lax.pmin(mn, axis)
+            mn_all = pmin(mn, axis)
             has_work = (mn < hz) & (mn < stop)
             # roughness suppression (cond-mat/0302050): a shard whose
             # frontier — or whose NEXT window — sits more than `spread`
@@ -289,7 +329,7 @@ def make_shard_run_to_async(step, hi: int, axis: str = AXIS,
             )
             adv = jnp.minimum(raw, jnp.maximum(frontier, cap))
             clipped = raw > adv  # null-advance suppressed by the cap
-            any_step = jax.lax.pmax(stepped.astype(jnp.int32), axis) > 0
+            any_step = pmax(stepped.astype(jnp.int32), axis) > 0
             # gap jump, exempt from the cap: it raises the MINIMUM
             # frontier too, so the surface moves up flat
             adv = jnp.where(
@@ -325,7 +365,7 @@ def make_shard_run_to_async(step, hi: int, axis: str = AXIS,
         f0 = jnp.minimum(
             jnp.minimum(
                 jnp.minimum(mn0, _bound(mn0)),
-                jax.lax.pmin(state.exch_deferred_min, axis),
+                pmin(state.exch_deferred_min, axis),
             ),
             stop,
         )
@@ -335,7 +375,7 @@ def make_shard_run_to_async(step, hi: int, axis: str = AXIS,
         )
         spread_max, steps, yields, blocked = stats
         return (
-            state, jax.lax.pmin(mn, axis), _press(state) > 0, _occ(state),
+            state, pmin(mn, axis), _press(state) > 0, _occ(state),
             w, frontier, spread_max, steps, yields, blocked,
         )
 
@@ -350,8 +390,8 @@ def make_shard_substep(step, axis: str = AXIS):
 
     def substep(state, params, ws, we):
         st2, mn2 = step(state, params, ws, we)
-        mn2 = jax.lax.pmin(mn2, axis)
-        viol = jax.lax.pmin(st2.xmit_min, axis)
+        mn2 = pmin(mn2, axis)
+        viol = pmin(st2.xmit_min, axis)
         return st2, mn2, viol
 
     return substep
@@ -836,16 +876,6 @@ class IslandSimulation(Simulation):
                 S, axis=AXIS, exclude=tuple(exclude_chips)
             )
             self.mesh = mesh
-            # jax >= 0.7 exposes jax.shard_map with the varying-manual-axes
-            # checker (check_vma); earlier releases ship the experimental
-            # module with the replication checker (check_rep). Both must be
-            # disabled for the same reason (see the sm() comment below).
-            shard_map = getattr(jax, "shard_map", None)
-            if shard_map is not None:
-                no_check = {"check_vma": False}
-            else:
-                from jax.experimental.shard_map import shard_map
-                no_check = {"check_rep": False}
 
             def _sq(tree):
                 return jax.tree.map(lambda x: x[0], tree)
@@ -869,7 +899,7 @@ class IslandSimulation(Simulation):
                         o[None] for o in out[1:]
                     )
 
-                wrapped = shard_map(
+                wrapped = jax.shard_map(
                     body, mesh=mesh,
                     in_specs=(state_spec, params_spec) + tuple(
                         P(AXIS) if sh else P() for sh in rest_shard
@@ -879,9 +909,9 @@ class IslandSimulation(Simulation):
                     # into varying state fields (e.g. state.now ← window
                     # start): semantically sound — every shard computes the
                     # identical value from the collective — but the static
-                    # varying/replication checker can't see that, so it is
+                    # varying-manual-axes checker can't see that, so it is
                     # disabled for these wrappers
-                    **no_check,
+                    check_vma=False,
                 )
                 return self._jit(wrapped)
 
@@ -940,7 +970,7 @@ class IslandSimulation(Simulation):
 
         def step_shard(state, params, ws, we):
             st, mn = step(state, params, ws, we)
-            return st, jax.lax.pmin(mn, AXIS)
+            return st, pmin(mn, AXIS)
 
         def run_to(state, params, stop, max_windows):
             return lane_run_to(state, params, runahead, stop, max_windows)

@@ -57,7 +57,8 @@ def build_parser() -> argparse.ArgumentParser:
         "--cache-dir", metavar="DIR",
         help="compile-cache root shared with bench.py (default "
              "$SHADOW_TPU_CACHE_DIR or <repo>/.jax_cache); AOT window-"
-             "kernel exports live under <DIR>/aot",
+             "kernel exports live under <DIR>/aot, XLA's cache in <DIR> "
+             "unless $JAX_COMPILATION_CACHE_DIR places it",
     )
     return p
 

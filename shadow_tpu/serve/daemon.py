@@ -923,7 +923,8 @@ class ShadowDaemon:
         from shadow_tpu.serve.kcache import enable_xla_cache
 
         # AOT entries skip Python re-traces; the XLA persistent cache
-        # (same root, shared with bench.py) skips the StableHLO→binary
+        # (same root unless $JAX_COMPILATION_CACHE_DIR places it, shared
+        # with bench.py) skips the StableHLO→binary
         # compile of a deserialized artifact — together a warm restart
         # redispatches in milliseconds
         enable_xla_cache(self.opts.cache_dir)

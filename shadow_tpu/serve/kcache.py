@@ -23,10 +23,13 @@ sha256 content digest and the producing jax/jaxlib versions. A corrupt,
 torn, or version-skewed entry is EVICTED and recompiled — never
 deserialized on faith (`evictions` counts them).
 
-The cache root is shared with bench.py's persistent XLA compile cache
-(`cache_root()`, overridable via SHADOW_TPU_CACHE_DIR): AOT entries live
-under `<root>/aot/`, XLA's own artifacts directly under `<root>`, so the
-daemon and the bench warm each other.
+The cache root (`cache_root()`: SHADOW_TPU_CACHE_DIR, else `.jax_cache`
+next to the repo) holds the AOT entries under `<root>/aot/` and, by
+default, XLA's own persistent cache directly under `<root>`, so the
+daemon, the bench and the chip smoke warm each other. Where
+JAX_COMPILATION_CACHE_DIR is set, XLA's cache lives there instead: that
+directory was placed from outside, so nothing here sets another one or
+deletes anything in it (`enable_xla_cache`).
 """
 
 from __future__ import annotations
@@ -46,8 +49,8 @@ def repo_root() -> str:
 
 
 def cache_root() -> str:
-    """The shared compile-cache root: SHADOW_TPU_CACHE_DIR when set,
-    else `.jax_cache` next to the repo (bench.py's historical default)."""
+    """The shared cache root: SHADOW_TPU_CACHE_DIR when set, else
+    `.jax_cache` next to the repo (a fixed path, so its entries hit)."""
     return os.environ.get("SHADOW_TPU_CACHE_DIR") or os.path.join(
         repo_root(), ".jax_cache"
     )
@@ -171,16 +174,26 @@ def sweep_corrupt_entries(root: str) -> int:
 
 
 def enable_xla_cache(root: str | None = None) -> tuple[str, int]:
-    """Point JAX's persistent compilation cache at the shared root
-    (evicting corrupt entries first) — one call shared by bench.py and
-    the serve daemon, so both warm the same cache. Returns
-    (root, evicted_count)."""
+    """Turn on JAX's persistent compilation cache — one call shared by
+    bench.py, the serve daemon, the tests and the chip smoke, so all warm
+    the same cache. Returns (cache_dir, evicted_count).
+
+    JAX_COMPILATION_CACHE_DIR, when set, wins over `root` and
+    `cache_root()`: JAX already reads it, it keys its entries by backend
+    itself, and it is never swept. Otherwise the cache lives at `root`
+    (default `cache_root()`), with foreign-machine and corrupt entries
+    evicted first."""
     import jax
 
-    root = root or cache_root()
-    os.makedirs(root, exist_ok=True)
-    evicted = _sweep_foreign_machine(root, machine_fingerprint())
-    evicted += sweep_corrupt_entries(root)
+    evicted = 0
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        root = placed
+    else:
+        root = root or cache_root()
+        os.makedirs(root, exist_ok=True)
+        evicted = _sweep_foreign_machine(root, machine_fingerprint())
+        evicted += sweep_corrupt_entries(root)
     jax.config.update("jax_compilation_cache_dir", root)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

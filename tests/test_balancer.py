@@ -67,8 +67,11 @@ def _cfg(shards=4, per=4, stop=8, skew_at="2 s", balancer=False,
             "app_options": {
                 "msgload": 2, "runtime": stop - 1,
                 # persistent destination bias toward shard 0's hosts —
-                # the skew amplification keeps re-concentrating there
-                "hot_frac": per / n, "hot_share": 0.5,
+                # the skew amplification keeps re-concentrating there.
+                # A share of 0.8 makes shard 0 the laggard under any
+                # seed; at 0.5 the heal's gain fell below the lookahead
+                # it costs on most seeds of the installed random stream
+                "hot_frac": per / n, "hot_share": 0.8,
             },
         }
     experimental = {

@@ -163,10 +163,10 @@ def test_resize_pool_grow_shrink_roundtrip():
     t = np.sort(rng.integers(1, 1 << 40, n))
     pool = pool.replace(
         time=pool.time.at[:n].set(t),
-        dst=pool.dst.at[:n].set(rng.integers(0, 8, n)),
-        src=pool.src.at[:n].set(rng.integers(0, 8, n)),
-        seq=pool.seq.at[:n].set(np.arange(n)),
-        kind=pool.kind.at[:n].set(rng.integers(0, 4, n)),
+        dst=pool.dst.at[:n].set(rng.integers(0, 8, n, dtype=np.int32)),
+        src=pool.src.at[:n].set(rng.integers(0, 8, n, dtype=np.int32)),
+        seq=pool.seq.at[:n].set(np.arange(n, dtype=np.int32)),
+        kind=pool.kind.at[:n].set(rng.integers(0, 4, n, dtype=np.int32)),
     )
     big, dropped = gearbox.resize_pool(pool, 128)
     assert big.capacity == 128 and int(dropped) == 0

@@ -46,6 +46,10 @@ graph [
   edge [ source 3 target 0 latency "85 ms" ]
   edge [ source 0 target 2 latency "60 ms" ]
   edge [ source 1 target 3 latency "75 ms" ]
+  edge [ source 0 target 0 latency "45 ms" ]
+  edge [ source 1 target 1 latency "50 ms" ]
+  edge [ source 2 target 2 latency "65 ms" ]
+  edge [ source 3 target 3 latency "80 ms" ]
 ]
 """
 

@@ -121,8 +121,13 @@ def test_classify_resource_exhausted_is_its_own_class():
     assert classify_failure(RuntimeError("ABORTED: collective")) == TRANSIENT
     assert classify_failure(RuntimeError("UNAVAILABLE: socket closed")) \
         == BACKEND_LOST
+    # a chip held by another process fails fast, even where the text
+    # also carries an UNAVAILABLE
     assert classify_failure(RuntimeError("device or resource busy")) \
-        == BACKEND_LOST
+        == FATAL
+    assert classify_failure(RuntimeError(
+        "Unable to initialize backend 'tpu': UNAVAILABLE: TPU in use")) \
+        == FATAL
     assert classify_failure(ValueError("shape mismatch")) == FATAL
 
 

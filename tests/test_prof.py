@@ -381,7 +381,7 @@ def test_config_profiler_knobs():
 
 
 # ---------------------------------------------------------------------------
-# tools (loaded the way tpu_watch invokes them)
+# tools (loaded the way a log scraper invokes them)
 # ---------------------------------------------------------------------------
 
 
@@ -437,7 +437,7 @@ def test_perf_compare_directions_and_rc(tmp_path):
     }
 
     # end to end: identical docs exit 0, a determinism breach exits 1,
-    # and --json emits ONE parseable line (tpu_watch scrapes per-line)
+    # and --json emits ONE parseable line (log scrapers read per-line)
     a = _metrics_doc(tmp_path, "a.json",
                      counters={"engine.events_committed": 5})
     b = _metrics_doc(tmp_path, "b.json",

@@ -162,7 +162,7 @@ def main(argv: list[str] | None = None) -> int:
     result["baseline"] = args.baseline
     result["candidate"] = args.candidate
     if args.json:
-        # one line so log scrapers (tools/tpu_watch.py) capture it whole
+        # one line so log scrapers capture it whole
         print(json.dumps(result))
     else:
         for row in result["regressions"]:

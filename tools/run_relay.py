@@ -12,6 +12,10 @@ test, then grep-verifies stream successes across both workloads.
 
 --rerun executes the whole network twice and also requires byte-identical
 circuit-client stdout across runs (determinism1_compare.cmake analog).
+
+This launcher never imports JAX: the simulation runs in a
+`python -m shadow_tpu` child, and a chip belongs to one process — a parent
+that touched JAX would hold it and the child would fail or hang.
 """
 
 from __future__ import annotations

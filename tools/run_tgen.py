@@ -10,6 +10,10 @@ file and reports PASS/FAIL.
 
     python tools/run_tgen.py --hosts 1024 --servers 32 --streams 2 \
         --bytes 8192 --data-dir /tmp/tgen1k.data
+
+This launcher never imports JAX: the simulation runs in a
+`python -m shadow_tpu` child, and a chip belongs to one process — a parent
+that touched JAX would hold it and the child would fail or hang.
 """
 
 from __future__ import annotations

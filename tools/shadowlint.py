@@ -29,8 +29,8 @@ Usage:
 Exit status: 0 when no non-baselined findings, 1 otherwise, 2 on an
 environment/usage failure (unparseable source, missing/corrupt HLO
 baseline, unknown rule code) — each exit-2 path prints a one-line
-remediation hint.  CI wiring: tools/tpu_watch.py runs the JSON form as a
-capture stage; ``bench.py --lint-smoke`` is the schema'd smoke gate.
+remediation hint.  ``bench.py --lint-smoke`` is the schema'd smoke
+gate.
 """
 
 from __future__ import annotations
@@ -93,10 +93,7 @@ def main(argv=None) -> int:
     if args.virtual_devices:
         from shadow_tpu.parallel.virtualize import force_cpu_devices
 
-        force_cpu_devices(
-            args.virtual_devices,
-            cache_dir=os.path.join(_REPO, ".jax_cache"),
-        )
+        force_cpu_devices(args.virtual_devices)
 
     from shadow_tpu.analysis import contracts, linter, threads
     from shadow_tpu.analysis.rules import RULE_INDEX
@@ -228,7 +225,7 @@ def main(argv=None) -> int:
     doc = linter.findings_doc(new, old, scanned, passes=passes)
 
     if args.format == "json":
-        # one line: tools/tpu_watch.py captures stage output line-wise
+        # one line: log scrapers capture stage output line-wise
         json.dump(doc, sys.stdout)
         sys.stdout.write("\n")
     else:

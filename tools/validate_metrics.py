@@ -6,7 +6,7 @@ A standalone CLI wrapper over `obs.metrics.validate_metrics_doc`
 including `--strict-namespaces` membership of the closed
 KNOWN_METRIC_NAMESPACES table, `qdisc.*` since schema v17 —
 come from obs/metrics.py, so this tool tracks every schema bump
-automatically): CI and tools/tpu_watch.py gate every
+automatically): CI gates every
 captured metrics artifact with this at capture time, so a schema
 regression is caught on the line that produced it, not months later by a
 consumer.
