@@ -654,7 +654,8 @@ def make_window_step(
                 ] - b
             return bd - gid[0]
 
-        _lrow = None if island is None else _box_lrow(state.pool.dst)
+        with jax.named_scope("win.extract"):
+            _lrow = None if island is None else _box_lrow(state.pool.dst)
 
         def _obs_win_bump(state, *slots):
             """One fused add to the telemetry block's window-plane row.
@@ -973,11 +974,11 @@ def make_window_step(
                 last_t = exec_t
                 for bt, bv in zip(bulk_exec, bulk_valid):
                     last_t = jnp.where(bv, bt, last_t)
-                state = state.replace(
-                    host=state.host.replace(
-                        done_t=jnp.where(valid, last_t, state.host.done_t)
+                with jax.named_scope("win.commit"):
+                    done_t = jnp.where(valid, last_t, state.host.done_t)
+                    state = state.replace(
+                        host=state.host.replace(done_t=done_t)
                     )
-                )
                 if with_cpu_model:
                     delay = jnp.where(valid, exec_t - ev_time, 0)
                     for bt, be, bv in zip(bulk_t, bulk_exec, bulk_valid):
@@ -1016,65 +1017,76 @@ def make_window_step(
                             )
                             state = handlers[k](state, gev, emitter, params)
 
-                state = state.replace(
-                    counters=state.counters.replace(
-                        events_committed=state.counters.events_committed
-                        + jnp.sum(valid, dtype=jnp.int64)
-                        + jnp.sum(taken_extra, dtype=jnp.int64),
-                        outbox_stall_deferred=state.counters.outbox_stall_deferred
-                        + jnp.sum(stalled, dtype=jnp.int64),
-                        micro_steps=state.counters.micro_steps + 1,
-                    )
-                )
-                if state.obs is not None:
-                    # telemetry block: per-host committed count + the
-                    # virtual-time frontier (events process in key order
-                    # per host, so a where-select IS the running max)
-                    ob = state.obs
-                    hd = ob.host_digest
-                    if audit:
-                        # determinism-audit chain (obs/audit.py): fold the
-                        # head event then each bulk column — per-host key
-                        # order, the order every engine layout commits in.
-                        # Keys use the ORIGINAL event time (not the CPU
-                        # model's exec shift), so chains are model-stable.
-                        hd = audit_mod.fold(
-                            hd, valid, ev_time, ev.src, gid, ev_kind
+                with jax.named_scope("win.commit"):
+                    c = state.counters
+                    state = state.replace(
+                        counters=c.replace(
+                            events_committed=c.events_committed
+                            + jnp.sum(valid, dtype=jnp.int64)
+                            + jnp.sum(taken_extra, dtype=jnp.int64),
+                            outbox_stall_deferred=c.outbox_stall_deferred
+                            + jnp.sum(stalled, dtype=jnp.int64),
+                            micro_steps=c.micro_steps + 1,
                         )
-                        for bt, bs, bv in zip(bulk_t, bulk_s, bulk_valid):
-                            hd = audit_mod.fold(
-                                hd, bv, bt, bs, gid, bulk_kind
-                            )
-                    state = state.replace(obs=ob.replace(
-                        host_events=ob.host_events
-                        + valid.astype(jnp.int64)
-                        + taken_extra.astype(jnp.int64),
-                        host_last_t=jnp.where(valid, last_t, ob.host_last_t),
-                        host_digest=hd,
-                    ))
-                if state.flight is not None:
-                    # flight recorder (obs/flight.py): append the committed
-                    # records at each host's ring cursor, head then bulk
-                    # columns — the same commit order the digest folds in
-                    fl = flight_mod.record(
-                        state.flight, valid, ev_time, ev.src, ev.seq,
-                        ev_kind,
                     )
-                    for bt, bs, bq, bv in zip(
-                        bulk_t, bulk_s, bulk_q, bulk_valid
-                    ):
-                        fl = flight_mod.record(fl, bv, bt, bs, bq, bulk_kind)
-                    state = state.replace(flight=fl)
+                    if state.obs is not None:
+                        # telemetry block: per-host committed count + the
+                        # virtual-time frontier (events process in key order
+                        # per host, so a where-select IS the running max)
+                        ob = state.obs
+                        hd = ob.host_digest
+                        if audit:
+                            # determinism-audit chain (obs/audit.py): fold
+                            # the head event then each bulk column — per-host
+                            # key order, the order every engine layout
+                            # commits in. Keys use the ORIGINAL event time
+                            # (not the CPU model's exec shift), so chains
+                            # are model-stable.
+                            hd = audit_mod.fold(
+                                hd, valid, ev_time, ev.src, gid, ev_kind
+                            )
+                            for bt, bs, bv in zip(
+                                bulk_t, bulk_s, bulk_valid
+                            ):
+                                hd = audit_mod.fold(
+                                    hd, bv, bt, bs, gid, bulk_kind
+                                )
+                        state = state.replace(obs=ob.replace(
+                            host_events=ob.host_events
+                            + valid.astype(jnp.int64)
+                            + taken_extra.astype(jnp.int64),
+                            host_last_t=jnp.where(
+                                valid, last_t, ob.host_last_t
+                            ),
+                            host_digest=hd,
+                        ))
+                    if state.flight is not None:
+                        # flight recorder (obs/flight.py): append the
+                        # committed records at each host's ring cursor, head
+                        # then bulk columns — the same commit order the
+                        # digest folds in
+                        fl = flight_mod.record(
+                            state.flight, valid, ev_time, ev.src, ev.seq,
+                            ev_kind,
+                        )
+                        for bt, bs, bq, bv in zip(
+                            bulk_t, bulk_s, bulk_q, bulk_valid
+                        ):
+                            fl = flight_mod.record(
+                                fl, bv, bt, bs, bq, bulk_kind
+                            )
+                        state = state.replace(flight=fl)
 
                 # --- route emissions (order fixes per-source seq numbers) ---
                 for em in emitter.records:
                     emp = soa.pack_words(em.payload)  # [H, PP]
-                    seq = state.host.seq_next
-                    state = state.replace(
-                        host=state.host.replace(
-                            seq_next=jnp.where(em.mask, seq + 1, seq)
+                    with jax.named_scope("win.commit"):
+                        seq = state.host.seq_next
+                        state = state.replace(
+                            host=state.host.replace(
+                                seq_next=jnp.where(em.mask, seq + 1, seq)
+                            )
                         )
-                    )
                     # Self-emissions at or past the host's earliest deferred
                     # leftover (full-key compare: exact under time ties) must
                     # not jump the queue: route them through the pool.
@@ -1113,16 +1125,23 @@ def make_window_step(
                         payload=_set_col(outbox.payload, ocol, put, emp),
                         count=outbox.count + put.astype(jnp.int32),
                     )
-                    state = state.replace(
-                        counters=state.counters.replace(
-                            events_emitted=state.counters.events_emitted
-                            + jnp.sum(em.mask, dtype=jnp.int64),
-                            inbox_overflow_deferred=state.counters.inbox_overflow_deferred
-                            + jnp.sum(is_self & ~has_free, dtype=jnp.int64),
-                            outbox_overflow_dropped=state.counters.outbox_overflow_dropped
-                            + jnp.sum(to_out & ~put, dtype=jnp.int64),
+                    with jax.named_scope("win.commit"):
+                        c = state.counters
+                        state = state.replace(
+                            counters=c.replace(
+                                events_emitted=c.events_emitted
+                                + jnp.sum(em.mask, dtype=jnp.int64),
+                                inbox_overflow_deferred=(
+                                    c.inbox_overflow_deferred
+                                    + jnp.sum(is_self & ~has_free,
+                                              dtype=jnp.int64)
+                                ),
+                                outbox_overflow_dropped=(
+                                    c.outbox_overflow_dropped
+                                    + jnp.sum(to_out & ~put, dtype=jnp.int64)
+                                ),
+                            )
                         )
-                    )
 
                 work = jnp.any(valid)
                 return (state, ptr, inbox, outbox, it + 1, work)
@@ -1162,67 +1181,75 @@ def make_window_step(
                     )
                     for w in range(PP)
                 ]
-                if bt.shape[0] and (island is None or island.optimistic):
-                    cross = (bd != bs) & (bt != NEVER)
-                    if island is None:
-                        dst_last = state.host.done_t[jnp.clip(bd, 0, H - 1)]
-                        violates = cross & (bt <= dst_last)
+                with jax.named_scope("win.commit"):
+                    if bt.shape[0] and (island is None or island.optimistic):
+                        cross = (bd != bs) & (bt != NEVER)
+                        done_t = state.host.done_t
+                        if island is None:
+                            dst_last = done_t[jnp.clip(bd, 0, H - 1)]
+                            violates = cross & (bt <= dst_last)
+                        else:
+                            # islands: only LOCAL-dst emissions can be
+                            # checked against this shard's progress clocks;
+                            # foreign ones are checked at ARRIVAL on their
+                            # owner (assemble's arrival_min) — no per-row
+                            # collective
+                            lr = _box_lrow(bd)
+                            loc = (lr >= 0) & (lr < H)
+                            dst_last = done_t[jnp.clip(lr, 0, H - 1)]
+                            violates = cross & loc & (bt <= dst_last)
+                        xmit_min = jnp.min(jnp.where(violates, bt, NEVER))
                     else:
-                        # islands: only LOCAL-dst emissions can be checked
-                        # against this shard's progress clocks; foreign
-                        # ones are checked at ARRIVAL on their owner
-                        # (assemble's arrival_min) — no per-row collective
-                        lr = _box_lrow(bd)
-                        loc = (lr >= 0) & (lr < H)
-                        dst_last = state.host.done_t[jnp.clip(lr, 0, H - 1)]
-                        violates = cross & loc & (bt <= dst_last)
-                    xmit_min = jnp.min(jnp.where(violates, bt, NEVER))
-                else:
-                    xmit_min = jnp.asarray(NEVER, jnp.int64)
+                        xmit_min = jnp.asarray(NEVER, jnp.int64)
                 state, arrival_min = assemble(
                     state, m_t, m_d, m_s, m_q, m_k, m_p
                 )
-                state = state.replace(
-                    xmit_min=jnp.minimum(xmit_min, arrival_min)
-                )
+                with jax.named_scope("win.commit"):
+                    state = state.replace(
+                        xmit_min=jnp.minimum(xmit_min, arrival_min)
+                    )
                 return state, jnp.min(state.pool.time)
 
             return carry0, cond, body, finish
 
         def run_loop(state):
-            state = _obs_win_bump(
-                state, obs_mod.WIN_WINDOWS, obs_mod.WIN_LOOP
-            )
-            dense, tail = _dense_extract(
-                state.pool, win_start, win_end, H, K + 1, PP, lrow=_lrow,
-            )
-            carry0, cond, body, finish = make_loop_fns(dense, tail)
-            state, ptr, inbox, outbox, _, _ = jax.lax.while_loop(
-                cond, body, (state,) + carry0
-            )
-            hostsB = jnp.broadcast_to(
-                gid[:, None], inbox.time.shape
-            ).reshape(-1)
-            return finish(
-                state, ptr,
-                jnp.concatenate(
-                    [outbox.time.reshape(-1), inbox.time.reshape(-1)]
-                ),
-                jnp.concatenate([outbox.dst.reshape(-1), hostsB]),
-                jnp.concatenate(
-                    [outbox.src.reshape(-1), inbox.src.reshape(-1)]
-                ),
-                jnp.concatenate(
-                    [outbox.seq.reshape(-1), inbox.seq.reshape(-1)]
-                ),
-                jnp.concatenate(
-                    [outbox.kind.reshape(-1), inbox.kind.reshape(-1)]
-                ),
-                jnp.concatenate(
-                    [outbox.payload.reshape(-1, PP),
-                     inbox.payload.reshape(-1, PP)]
-                ),
-            )
+            with jax.named_scope("win.commit"):
+                state = _obs_win_bump(
+                    state, obs_mod.WIN_WINDOWS, obs_mod.WIN_LOOP
+                )
+            with jax.named_scope("win.extract"):
+                dense, tail = _dense_extract(
+                    state.pool, win_start, win_end, H, K + 1, PP, lrow=_lrow,
+                )
+            with jax.named_scope("win.handlers"):
+                carry0, cond, body, finish = make_loop_fns(dense, tail)
+                state, ptr, inbox, outbox, _, _ = jax.lax.while_loop(
+                    cond, body, (state,) + carry0
+                )
+            with jax.named_scope("win.merge"):
+                hostsB = jnp.broadcast_to(
+                    gid[:, None], inbox.time.shape
+                ).reshape(-1)
+                return finish(
+                    state, ptr,
+                    jnp.concatenate(
+                        [outbox.time.reshape(-1), inbox.time.reshape(-1)]
+                    ),
+                    jnp.concatenate([outbox.dst.reshape(-1), hostsB]),
+                    jnp.concatenate(
+                        [outbox.src.reshape(-1), inbox.src.reshape(-1)]
+                    ),
+                    jnp.concatenate(
+                        [outbox.seq.reshape(-1), inbox.seq.reshape(-1)]
+                    ),
+                    jnp.concatenate(
+                        [outbox.kind.reshape(-1), inbox.kind.reshape(-1)]
+                    ),
+                    jnp.concatenate(
+                        [outbox.payload.reshape(-1, PP),
+                         inbox.payload.reshape(-1, PP)]
+                    ),
+                )
 
         def run_matrix(state):
             """Whole-window vectorized path: when EVERY in-window event has
@@ -1236,209 +1263,227 @@ def make_window_step(
             element) while multi-operand sorts and scans run at memory
             bandwidth, so this path is built from sorts, cumulative scans,
             and reshapes ONLY (_dense_extract)."""
-            state = _obs_win_bump(
-                state, obs_mod.WIN_WINDOWS, obs_mod.WIN_MATRIX
-            )
-            pool = state.pool
-            dense, tail = _dense_extract(
-                pool, win_start, win_end, H, K, PP, lrow=_lrow
-            )
-            d_t, d_s, d_q = dense.time, dense.src, dense.seq
-            d_p = dense.payload
-            # fillers interleave with real same-host rows only at time
-            # NEVER, so a dense cell is real iff its time is set
-            valid = d_t != NEVER
-            nvalid = jnp.sum(valid, axis=1, dtype=jnp.int32)
-            if with_cpu_model:
-                # CPU serialization as a scan (same semantics as the loop
-                # path's per-event chain): exec_k = max(t_k, exec_{k-1} +
-                # cost). With u_k = exec_k - k*cost this is a cummax of
-                # (t_k - k*cost) floored at cpu_avail.
-                cost = state.host.cpu_cost[:, None]  # [H, 1]
-                ks = jnp.arange(valid.shape[1], dtype=jnp.int64)[None, :]
-                shifted = jnp.where(
-                    valid, d_t - ks * cost, jnp.int64(-(1 << 62))
+            with jax.named_scope("win.commit"):
+                state = _obs_win_bump(
+                    state, obs_mod.WIN_WINDOWS, obs_mod.WIN_MATRIX
                 )
-                u = jax.lax.cummax(shifted, axis=1)
-                u = jnp.maximum(u, state.host.cpu_avail[:, None])
-                exec_t = jnp.where(valid, u + ks * cost, d_t)
-                last_exec = soa.get_at(
-                    exec_t, jnp.maximum(nvalid - 1, 0)
+            with jax.named_scope("win.extract"):
+                pool = state.pool
+                dense, tail = _dense_extract(
+                    pool, win_start, win_end, H, K, PP, lrow=_lrow
+                )
+                d_t, d_s, d_q = dense.time, dense.src, dense.seq
+                d_p = dense.payload
+                # fillers interleave with real same-host rows only at time
+                # NEVER, so a dense cell is real iff its time is set
+                valid = d_t != NEVER
+                nvalid = jnp.sum(valid, axis=1, dtype=jnp.int32)
+            with jax.named_scope("win.handlers"):
+                if with_cpu_model:
+                    # CPU serialization as a scan (same semantics as the loop
+                    # path's per-event chain): exec_k = max(t_k, exec_{k-1} +
+                    # cost). With u_k = exec_k - k*cost this is a cummax of
+                    # (t_k - k*cost) floored at cpu_avail.
+                    cost = state.host.cpu_cost[:, None]  # [H, 1]
+                    ks = jnp.arange(valid.shape[1], dtype=jnp.int64)[None, :]
+                    shifted = jnp.where(
+                        valid, d_t - ks * cost, jnp.int64(-(1 << 62))
+                    )
+                    u = jax.lax.cummax(shifted, axis=1)
+                    u = jnp.maximum(u, state.host.cpu_avail[:, None])
+                    exec_t = jnp.where(valid, u + ks * cost, d_t)
+                    last_exec = soa.get_at(
+                        exec_t, jnp.maximum(nvalid - 1, 0)
+                    )
+                    state = state.replace(
+                        host=state.host.replace(
+                            cpu_avail=jnp.where(
+                                nvalid > 0,
+                                last_exec + state.host.cpu_cost,
+                                state.host.cpu_avail,
+                            )
+                        ),
+                        counters=state.counters.replace(
+                            cpu_delay_applied=state.counters.cpu_delay_applied
+                            + jnp.sum(
+                                jnp.where(valid, exec_t - d_t, 0),
+                                dtype=jnp.int64,
+                            )
+                        ),
+                    )
+                else:
+                    exec_t = d_t
+                mv = MatrixEventView(
+                    mask=valid, time=exec_t, src=d_s, seq=d_q,
+                    payload=soa.unpack_words(d_p, P),
+                )
+                memit = MatrixEmitter()
+                state = matrix_handlers[bulk_kind](state, mv, memit, params)
+            with jax.named_scope("win.commit"):
+                last_t = jnp.max(
+                    jnp.where(valid, exec_t, jnp.int64(-1)), axis=1
+                )
+                done_t = jnp.where(nvalid > 0, last_t, state.host.done_t)
+                state = state.replace(host=state.host.replace(done_t=done_t))
+                # per-source sequence numbers: per host, emissions are
+                # ordered column-major (event order), record-minor within a
+                # column — identical to the loop path's per-event record
+                # order
+                base = state.host.seq_next
+                masks = [r.mask.astype(jnp.int32) for r in memit.records]
+                per_col = (
+                    sum(masks) if masks else jnp.zeros((H, K), jnp.int32)
+                )
+                col_excl = jnp.cumsum(per_col, axis=1) - per_col
+                seen = jnp.zeros((H, K), dtype=jnp.int32)
+                # per record: (time, dst, src, seq, kind, pcols)
+                em_rows = []
+                hostsK = jnp.broadcast_to(gid[:, None], (H, K))
+                for j, r in enumerate(memit.records):
+                    seqj = base[:, None] + col_excl + seen
+                    seen = seen + masks[j]
+                    rp = soa.pack_words(r.payload)  # [H, K, PP]
+                    em_rows.append((
+                        jnp.where(r.mask, r.time, NEVER).reshape(-1),
+                        r.dst.reshape(-1),
+                        hostsK.reshape(-1),
+                        seqj.reshape(-1),
+                        r.kind.reshape(-1),
+                        [rp[:, :, w].reshape(-1) for w in range(PP)],
+                    ))
+                total = jnp.sum(per_col, axis=1, dtype=jnp.int32)
+                state = state.replace(
+                    host=state.host.replace(seq_next=base + total)
+                )
+                # bulk-contract check (make_window_step docstring): the
+                # matrix path is only sound if no emission targets SELF below
+                # win_end — such an emission would deserve to interleave
+                # with this window's batched events. Count violations
+                # loudly.
+                viol = jnp.zeros((), jnp.int64)
+                for r in memit.records:
+                    viol = viol + jnp.sum(
+                        r.mask & (r.dst == hostsK) & (r.time < win_end),
+                        dtype=jnp.int64,
+                    )
+                state = state.replace(
+                    counters=state.counters.replace(
+                        bulk_contract_violations=(
+                            state.counters.bulk_contract_violations + viol
+                        )
+                    )
                 )
                 state = state.replace(
-                    host=state.host.replace(
-                        cpu_avail=jnp.where(
-                            nvalid > 0,
-                            last_exec + state.host.cpu_cost,
-                            state.host.cpu_avail,
-                        )
-                    ),
                     counters=state.counters.replace(
-                        cpu_delay_applied=state.counters.cpu_delay_applied
-                        + jnp.sum(
-                            jnp.where(valid, exec_t - d_t, 0),
-                            dtype=jnp.int64,
-                        )
-                    ),
-                )
-            else:
-                exec_t = d_t
-            mv = MatrixEventView(
-                mask=valid, time=exec_t, src=d_s, seq=d_q,
-                payload=soa.unpack_words(d_p, P),
-            )
-            memit = MatrixEmitter()
-            state = matrix_handlers[bulk_kind](state, mv, memit, params)
-            last_t = jnp.max(jnp.where(valid, exec_t, jnp.int64(-1)), axis=1)
-            state = state.replace(
-                host=state.host.replace(
-                    done_t=jnp.where(nvalid > 0, last_t, state.host.done_t)
-                )
-            )
-            # per-source sequence numbers: per host, emissions are ordered
-            # column-major (event order), record-minor within a column —
-            # identical to the loop path's per-event record order
-            base = state.host.seq_next
-            masks = [r.mask.astype(jnp.int32) for r in memit.records]
-            per_col = sum(masks) if masks else jnp.zeros((H, K), jnp.int32)
-            col_excl = jnp.cumsum(per_col, axis=1) - per_col
-            seen = jnp.zeros((H, K), dtype=jnp.int32)
-            em_rows = []  # per record: (time, dst, src, seq, kind, pcols)
-            hostsK = jnp.broadcast_to(gid[:, None], (H, K))
-            for j, r in enumerate(memit.records):
-                seqj = base[:, None] + col_excl + seen
-                seen = seen + masks[j]
-                rp = soa.pack_words(r.payload)  # [H, K, PP]
-                em_rows.append((
-                    jnp.where(r.mask, r.time, NEVER).reshape(-1),
-                    r.dst.reshape(-1),
-                    hostsK.reshape(-1),
-                    seqj.reshape(-1),
-                    r.kind.reshape(-1),
-                    [rp[:, :, w].reshape(-1) for w in range(PP)],
-                ))
-            total = jnp.sum(per_col, axis=1, dtype=jnp.int32)
-            state = state.replace(
-                host=state.host.replace(seq_next=base + total)
-            )
-            # bulk-contract check (make_window_step docstring): the matrix
-            # path is only sound if no emission targets SELF below win_end —
-            # such an emission would deserve to interleave with this
-            # window's batched events. Count violations loudly.
-            viol = jnp.zeros((), jnp.int64)
-            for r in memit.records:
-                viol = viol + jnp.sum(
-                    r.mask & (r.dst == hostsK) & (r.time < win_end),
-                    dtype=jnp.int64,
-                )
-            state = state.replace(
-                counters=state.counters.replace(
-                    bulk_contract_violations=(
-                        state.counters.bulk_contract_violations + viol
+                        events_committed=state.counters.events_committed
+                        + jnp.sum(valid, dtype=jnp.int64),
+                        events_emitted=state.counters.events_emitted
+                        + jnp.sum(per_col, dtype=jnp.int64),
+                        micro_steps=state.counters.micro_steps + 1,
                     )
                 )
-            )
-            state = state.replace(
-                counters=state.counters.replace(
-                    events_committed=state.counters.events_committed
-                    + jnp.sum(valid, dtype=jnp.int64),
-                    events_emitted=state.counters.events_emitted
-                    + jnp.sum(per_col, dtype=jnp.int64),
-                    micro_steps=state.counters.micro_steps + 1,
-                )
-            )
-            if state.obs is not None:
-                ob = state.obs
-                hd = ob.host_digest
-                if audit:
-                    # audit chain over the dense window, column by column —
-                    # per-host key order, identical to the loop path's
-                    # micro-step commit order, so either dispatch path of
-                    # the same window folds the same chain
+                if state.obs is not None:
+                    ob = state.obs
+                    hd = ob.host_digest
+                    if audit:
+                        # audit chain over the dense window, column by
+                        # column — per-host key order, identical to the loop
+                        # path's micro-step commit order, so either dispatch
+                        # path of the same window folds the same chain
+                        for j in range(K):
+                            hd = audit_mod.fold(
+                                hd, valid[:, j], d_t[:, j], d_s[:, j], gid,
+                                dense.kind[:, j],
+                            )
+                    state = state.replace(obs=ob.replace(
+                        host_events=ob.host_events
+                        + jnp.sum(valid, axis=1, dtype=jnp.int64),
+                        host_last_t=jnp.where(
+                            nvalid > 0, last_t, ob.host_last_t
+                        ),
+                        host_digest=hd,
+                    ))
+                if state.flight is not None:
+                    fl = state.flight
                     for j in range(K):
-                        hd = audit_mod.fold(
-                            hd, valid[:, j], d_t[:, j], d_s[:, j], gid,
-                            dense.kind[:, j],
+                        fl = flight_mod.record(
+                            fl, valid[:, j], d_t[:, j], d_s[:, j],
+                            d_q[:, j], dense.kind[:, j],
                         )
-                state = state.replace(obs=ob.replace(
-                    host_events=ob.host_events
-                    + jnp.sum(valid, axis=1, dtype=jnp.int64),
-                    host_last_t=jnp.where(
-                        nvalid > 0, last_t, ob.host_last_t
-                    ),
-                    host_digest=hd,
-                ))
-            if state.flight is not None:
-                fl = state.flight
-                for j in range(K):
-                    fl = flight_mod.record(
-                        fl, valid[:, j], d_t[:, j], d_s[:, j], d_q[:, j],
-                        dense.kind[:, j],
+                    state = state.replace(flight=fl)
+            with jax.named_scope("win.merge"):
+                # --- merge (sort 3): tail leftovers ∪ emissions, ONE 1-key
+                # stable sort by time carrying every column; no payload
+                # indirection gathers. Output truncates to pool capacity
+                # (fillers sit at time NEVER and fall off first). ---
+                m_t = jnp.concatenate([tail.time] + [e[0] for e in em_rows])
+                m_d = jnp.concatenate([tail.dst] + [e[1] for e in em_rows])
+                m_s = jnp.concatenate([tail.src] + [e[2] for e in em_rows])
+                m_q = jnp.concatenate([tail.seq] + [e[3] for e in em_rows])
+                m_k = jnp.concatenate([tail.kind] + [e[4] for e in em_rows])
+                m_p = [
+                    jnp.concatenate(
+                        [tail.payload[w]] + [e[5][w] for e in em_rows]
                     )
-                state = state.replace(flight=fl)
-            # --- merge (sort 3): tail leftovers ∪ emissions, ONE 1-key
-            # stable sort by time carrying every column; no payload
-            # indirection gathers. Output truncates to pool capacity
-            # (fillers sit at time NEVER and fall off first). ---
-            m_t = jnp.concatenate([tail.time] + [e[0] for e in em_rows])
-            m_d = jnp.concatenate([tail.dst] + [e[1] for e in em_rows])
-            m_s = jnp.concatenate([tail.src] + [e[2] for e in em_rows])
-            m_q = jnp.concatenate([tail.seq] + [e[3] for e in em_rows])
-            m_k = jnp.concatenate([tail.kind] + [e[4] for e in em_rows])
-            m_p = [
-                jnp.concatenate([tail.payload[w]] + [e[5][w] for e in em_rows])
-                for w in range(PP)
-            ]
-            state, arrival_min = assemble(state, m_t, m_d, m_s, m_q, m_k, m_p)
-            # speculation-violation signal (optimistic synchronizer): the
-            # one place a by-dst lookup is unavoidable; emissions are the
-            # only candidate violators (leftovers already lived in the pool)
-            if em_rows and (island is None or island.optimistic):
-                e_t = jnp.concatenate([e[0] for e in em_rows])
-                e_d = jnp.concatenate([e[1] for e in em_rows])
-                e_s = jnp.concatenate([e[2] for e in em_rows])
+                    for w in range(PP)
+                ]
+                state, arrival_min = assemble(
+                    state, m_t, m_d, m_s, m_q, m_k, m_p
+                )
+            with jax.named_scope("win.commit"):
+                # speculation-violation signal (optimistic synchronizer):
+                # the one place a by-dst lookup is unavoidable; emissions are
+                # the only candidate violators (leftovers already lived in
+                # the pool)
+                if em_rows and (island is None or island.optimistic):
+                    e_t = jnp.concatenate([e[0] for e in em_rows])
+                    e_d = jnp.concatenate([e[1] for e in em_rows])
+                    e_s = jnp.concatenate([e[2] for e in em_rows])
 
-                def _exact(_):
-                    # the one unavoidable by-dst lookup (a serialized
-                    # gather on TPU) — only reached when a violation is
-                    # even possible, i.e. under optimistic long windows
-                    if island is None:
-                        dst_last = state.host.done_t[jnp.clip(e_d, 0, H - 1)]
-                        viol = (
-                            (e_d != e_s) & (e_t != NEVER) & (e_t <= dst_last)
-                        )
-                    else:
-                        # local-dst only; foreign emissions are covered by
-                        # assemble's arrival check on the owner shard
-                        lr = _box_lrow(e_d)
-                        loc = (lr >= 0) & (lr < H)
-                        dst_last = state.host.done_t[jnp.clip(lr, 0, H - 1)]
-                        viol = (
-                            (e_d != e_s) & loc & (e_t != NEVER)
-                            & (e_t <= dst_last)
-                        )
-                    return jnp.min(jnp.where(viol, e_t, NEVER))
+                    def _exact(_):
+                        # the one unavoidable by-dst lookup (a serialized
+                        # gather on TPU) — only reached when a violation is
+                        # even possible, i.e. under optimistic long windows
+                        done_t = state.host.done_t
+                        if island is None:
+                            dst_last = done_t[jnp.clip(e_d, 0, H - 1)]
+                            viol = (
+                                (e_d != e_s) & (e_t != NEVER)
+                                & (e_t <= dst_last)
+                            )
+                        else:
+                            # local-dst only; foreign emissions are covered
+                            # by assemble's arrival check on the owner shard
+                            lr = _box_lrow(e_d)
+                            loc = (lr >= 0) & (lr < H)
+                            dst_last = done_t[jnp.clip(lr, 0, H - 1)]
+                            viol = (
+                                (e_d != e_s) & loc & (e_t != NEVER)
+                                & (e_t <= dst_last)
+                            )
+                        return jnp.min(jnp.where(viol, e_t, NEVER))
 
-                possible = jnp.min(e_t) <= jnp.max(state.host.done_t)
+                    possible = jnp.min(e_t) <= jnp.max(state.host.done_t)
 
-                def _never(_):
-                    never = jnp.asarray(NEVER, jnp.int64)
-                    if island is not None:
-                        # under shard_map the true branch's output varies
-                        # over the islands axis; the constant must be cast
-                        # to the same varying type or cond rejects it
-                        never = jax.lax.pcast(
-                            never, (island.axis,), to="varying"
-                        )
-                    return never
+                    def _never(_):
+                        never = jnp.asarray(NEVER, jnp.int64)
+                        if island is not None:
+                            # under shard_map the true branch's output varies
+                            # over the islands axis; the constant must be cast
+                            # to the same varying type or cond rejects it
+                            never = jax.lax.pcast(
+                                never, (island.axis,), to="varying"
+                            )
+                        return never
 
-                xmit_min = jax.lax.cond(possible, _exact, _never, 0)
-            else:
-                xmit_min = jnp.asarray(NEVER, jnp.int64)
-            state = state.replace(
-                xmit_min=jnp.minimum(xmit_min, arrival_min)
-            )
-            return state, jnp.min(state.pool.time)
+                    xmit_min = jax.lax.cond(possible, _exact, _never, 0)
+                else:
+                    xmit_min = jnp.asarray(NEVER, jnp.int64)
+                state = state.replace(
+                    xmit_min=jnp.minimum(xmit_min, arrival_min)
+                )
+            with jax.named_scope("win.merge"):
+                return state, jnp.min(state.pool.time)
 
         if bulk_kind is None or bulk_kind not in matrix_handlers:
             return run_loop(state)
@@ -1539,6 +1584,11 @@ def make_attempt(step):
         )
 
     return attempt
+
+
+def _counters_dict(c) -> dict[str, int]:
+    """A host copy of the Counters struct as {field: int}, by name."""
+    return {k: int(v) for k, v in sorted(c.__dict__.items())}
 
 
 # ---------------------------------------------------------------------------
@@ -1687,6 +1737,10 @@ class Simulation:
         # (--metrics-out/--trace-out) or bench; None keeps the run loops on
         # their zero-instrumentation path.
         self.obs_session = None
+        # The committed Counters as the fused driver fetched them with its
+        # dispatch, held on the host while the session reads them at the
+        # boundary (obs/counters.committed); None everywhere else.
+        self.boundary_counters = None
         # Determinism-audit trail + flight spool (obs/audit.py /
         # obs/flight.py): attached by --digest-out / --flight-out; None
         # keeps every handoff free of the extra obs-block fetch.
@@ -2321,10 +2375,22 @@ class Simulation:
 
         def fetch(out):
             st, mn, press, occ = out
-            # blocking fetches INSIDE the supervised await: async-
-            # dispatch errors must surface here, not at a later
-            # unsupervised sync
-            return st, int(mn), bool(press), int(occ)
+            obs = self.obs_session
+            if obs is None:
+                # blocking fetches INSIDE the supervised await: async-
+                # dispatch errors must surface here, not at a later
+                # unsupervised sync
+                return st, int(mn), bool(press), int(occ), None
+            # with a session: the device wait and the host reads are
+            # spans of their own, and the committed counters ride the
+            # same transfer, so round_done reads no device state
+            with metrics_mod.span(obs, "wait"):
+                jax.block_until_ready((mn, press, occ))
+            with metrics_mod.span(obs, "fetch"):
+                mn, press, occ, c = jax.device_get(
+                    (mn, press, occ, st.counters)
+                )
+            return st, int(mn), bool(press), int(occ), _counters_dict(c)
 
         return issue, fetch
 
@@ -2370,10 +2436,12 @@ class Simulation:
                         p = self._sv_issue(
                             "run_to", *self._run_to_halves(stop_at, wpd)
                         )
-                        self.state, mn, press, occ = self._sv_await(p)
+                        self.state, mn, press, occ, held = self._sv_await(p)
                 else:
                     with metrics_mod.span(obs, "await", windows=wpd):
-                        self.state, mn, press, occ = self._sv_await(pending)
+                        self.state, mn, press, occ, held = self._sv_await(
+                            pending
+                        )
                 # two-slot pipeline: issue dispatch N+1 asynchronously
                 # BEFORE draining dispatch N's handoff — the device
                 # computes while the host drains; state-mutating ticks
@@ -2406,7 +2474,9 @@ class Simulation:
                 with metrics_mod.span(obs, "host_drain"):
                     self._gear_note_dispatch()
                     if obs is not None:
+                        self.boundary_counters = held
                         obs.round_done(self, mn)
+                        self.boundary_counters = None
                     self._audit_tick(mn)
                     # gearing: a red-zone early exit upshifts (one pool
                     # re-sort) before the spill tier would pay host drain
@@ -3146,8 +3216,7 @@ class Simulation:
         return d
 
     def counters(self) -> dict[str, int]:
-        c = jax.device_get(self.state.counters)
-        return {k: int(v) for k, v in sorted(c.__dict__.items())}
+        return _counters_dict(jax.device_get(self.state.counters))
 
     def obs_snapshot(self) -> dict:
         """The device telemetry block (obs/counters.py), normalized across

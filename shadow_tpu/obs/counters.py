@@ -132,6 +132,15 @@ def snapshot(state) -> dict:
     }
 
 
+def committed_counters(sim) -> dict[str, int]:
+    """The engine Counters at a dispatch boundary: the host copy the fused
+    driver fetched with the dispatch's own outputs (`boundary_counters`,
+    set only while the boundary's session reads it), else a device read
+    (`sim.counters()`), as every other driver needs."""
+    held = getattr(sim, "boundary_counters", None)
+    return held if held is not None else sim.counters()
+
+
 def vtime_stats(host_last_t: np.ndarray) -> dict:
     """Virtual-time-roughness statistics over the per-host committed-time
     frontier (cond-mat/0302050's spread metric): hosts that committed

@@ -653,10 +653,14 @@ class ObsSession:
     def span(self, name: str, **args):
         """Wall-time span: observed into `wall.{name}_s` and (when tracing)
         emitted as a Chrome complete event. The FIRST dispatch span also
-        lands in `wall.first_dispatch_s` — it includes XLA compilation."""
+        lands in `wall.first_dispatch_s` — it includes XLA compilation.
+        It is also a `jax.profiler.TraceAnnotation`, so a profiler trace
+        shows the span on the host plane, on the device ops' clock."""
+        from jax.profiler import TraceAnnotation
+
         t0 = time.perf_counter()
         tr = self.tracer.span(name, **args) if self.tracer else nullcontext()
-        with tr:
+        with tr, TraceAnnotation(name):
             yield
         dt = time.perf_counter() - t0
         self.metrics.histogram(f"wall.{name}_s").observe(dt)
@@ -678,7 +682,7 @@ class ObsSession:
         pass the committed frontier they fetched anyway; the profiling
         recorder stamps its interval ring with it."""
         now = time.perf_counter()
-        ev = sim.counters()["events_committed"]
+        ev = obs_counters.committed_counters(sim)["events_committed"]
         dt = now - self._last_wall
         if dt > 0 and ev > self._last_events:
             self.metrics.histogram("round.events_per_sec").observe(

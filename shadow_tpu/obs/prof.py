@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import time
 
+from shadow_tpu.obs.counters import committed_counters
 from shadow_tpu.obs.hist import LogHistogram
 
 PROFILE_SCHEMA_VERSION = 1
@@ -111,7 +112,7 @@ class ProfRecorder:
         """Record one interval at a handoff boundary: read-only against
         the sim (host-cached counters + the already-fetched frontier),
         so the profiled schedule is the unprofiled schedule."""
-        c = sim.counters()
+        c = committed_counters(sim)
         events = int(c.get("events_committed", 0))
         windows = int(getattr(sim, "windows_run", 0) or 0)
         shard = None

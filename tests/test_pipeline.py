@@ -461,6 +461,57 @@ def test_trace_spans_and_overlap_efficiency(tmp_path):
     assert trace_summary.overlap_stats(serial) is None
 
 
+def test_fetch_half_splits_into_wait_and_fetch_spans():
+    from shadow_tpu.obs.trace import ChromeTracer
+
+    sim = build_simulation(_cfg())
+    tracer = ChromeTracer()
+    sim.obs_session = obs_metrics.ObsSession(tracer=tracer)
+    sim.run(windows_per_dispatch=16)
+    spans = [e for e in tracer.to_doc()["traceEvents"] if e.get("ph") == "X"]
+
+    def parent(e):
+        for p in spans:
+            if (p["name"] in ("await", "dispatch")
+                    and p["ts"] <= e["ts"]
+                    and e["ts"] + e["dur"] <= p["ts"] + p["dur"]):
+                return p["name"]
+        return None
+
+    kids = [e for e in spans if e["name"] in ("wait", "fetch")]
+    assert {e["name"] for e in kids} == {"wait", "fetch"}
+    # one wait and one fetch per awaited dispatch, each inside its parent
+    assert all(parent(e) for e in kids)
+    n_await = sum(e["name"] == "await" for e in spans)
+    assert n_await > 0
+    assert sum(parent(e) == "await" for e in kids) == 2 * n_await
+
+
+def test_session_reads_counters_fetched_with_the_dispatch():
+    from shadow_tpu.obs.prof import ProfRecorder
+
+    sim = build_simulation(_cfg())
+    prof = ProfRecorder()
+    session = obs_metrics.ObsSession(prof=prof)
+    sim.obs_session = session
+    device_read = sim.counters
+
+    def no_read():
+        raise AssertionError("a device read at the dispatch boundary")
+
+    sim.counters = no_read
+    sim.run(windows_per_dispatch=16)
+    sim.counters = device_read
+    assert sim.boundary_counters is None
+    total = sim.counters()["events_committed"]
+    assert total > 0
+    # the boundaries' host copies add up to the committed totals
+    assert session._last_events == total
+    assert sum(r["d_events"] for r in prof.intervals()) == total
+    h = session.metrics.histogram("round.events_per_sec")
+    assert h.count > 0 and h.min > 0
+
+
 def test_handoff_hook_runs_and_mutation_discards_speculation():
     seen = []
 
