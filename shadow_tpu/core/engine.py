@@ -253,21 +253,39 @@ def _pack2(hi, lo):
     return (hi.astype(jnp.int64) << 32) | (lo.astype(jnp.int64) & _LO32)
 
 
-def _unpack2(x):
-    return (x >> 32).astype(jnp.int32), x.astype(jnp.int32)
-
-
 def _take_rows(cols, perm):
-    """Gather the int64 row columns `cols` through `perm` as ONE whole-row
-    gather of their [N, len(cols)] stack; returns the columns.
+    """Gather the row columns `cols` (each [N], int32 or int64) through
+    `perm` as ONE gather of an [N, W] int32 word matrix; returns the
+    columns in their own dtypes, bit for bit.
+
+    The TPU has no 64-bit vector unit: it lowers an int64 gather as two
+    u32 gathers, one per half-word, and each pays the full per-index cost
+    (v5e, 720,896 rows: 4.3 ns per index for each u32 half of an int64
+    [N, 4] gather, 3.3 ns per index for one int32 [N, 8] gather). So an
+    int64 column rides as its two words and an int32 column as itself,
+    and one index pass moves the whole row: W is 2 per int64 column plus
+    1 per int32 column. The split
+    is a bitcast: splitting with shifts and masks made the compiler fuse
+    the commit phase's audit fold into 32 kernels instead of 5 (v5e: +0.8
+    ms per window at 16,384 hosts).
 
     The sorts below carry only their keys and a row index: TPU compile
     time grows with a sort's operand count (described v5e, PR 21: ~50 s
     per int64 operand at 165k rows; the 11-operand window sorts took the
     udp_flood_10k program to ~19 min), so every payload column follows
     the sort's permutation here instead of riding it as an operand."""
-    rows = jnp.take(jnp.stack(cols, axis=1), perm, axis=0, mode="clip")
-    return [rows[:, i] for i in range(len(cols))]
+    words = [jax.lax.bitcast_convert_type(c, jnp.int32)
+             if c.dtype == jnp.int64 else c[:, None] for c in cols]
+    rows = jnp.take(jnp.concatenate(words, axis=1), perm, axis=0,
+                    mode="clip")
+    out, i = [], 0
+    for c, w in zip(cols, words):
+        n = w.shape[1]
+        r = rows[:, i:i + n]
+        out.append(jax.lax.bitcast_convert_type(r, c.dtype) if n == 2
+                   else r[:, 0])
+        i += n
+    return out
 
 
 class IslandSpec(NamedTuple):
@@ -350,12 +368,15 @@ def _island_route(
     k1_r = (group << _DT_BITS) | dt
     gf = jnp.repeat(jnp.arange(S, dtype=jnp.int64), X)
     k1_f = (gf << _DT_BITS) | _DT_MAX
+    z32 = jnp.zeros((SX,), jnp.int32)
     z64 = jnp.zeros((SX,), jnp.int64)
     n_r = k1_r.shape[0]
     cat_cols = [
         jnp.concatenate([m_t, jnp.full((SX,), NEVER, jnp.int64)]),
-        jnp.concatenate([_pack2(m_d, m_k), z64]),
-        jnp.concatenate([_pack2(m_s, m_q), z64]),
+        jnp.concatenate([m_d, z32]),
+        jnp.concatenate([m_s, z32]),
+        jnp.concatenate([m_q, z32]),
+        jnp.concatenate([m_k, z32]),
     ] + [jnp.concatenate([p, z64]) for p in m_p]
     N = n_r + SX
     iota = jnp.arange(N, dtype=jnp.int32)
@@ -376,10 +397,7 @@ def _island_route(
     k2 = (slot << _DT_BITS) | (s_k1 & _DT_MAX)
     # ties in k2 keep the first sort's order (iota), as a stable sort would
     _, _, perm = jax.lax.sort([k2, iota, perm1], num_keys=2)
-    g_t, g_dk, g_sq, *g_p = _take_rows(cat_cols, perm)
-    g_d, g_k = _unpack2(g_dk)
-    g_s, g_q = _unpack2(g_sq)
-    cols = [g_t, g_d, g_s, g_q, g_k] + g_p
+    cols = _take_rows(cat_cols, perm)  # t, d, s, q, k, packed payload
     # filler rows (cat index >= n_r) carry time NEVER: not sent
     sent = jnp.sum(extract & (perm1 < n_r), dtype=jnp.int64)
 
@@ -466,13 +484,15 @@ def _dense_extract(pool: EventPool, win_start, win_end, H: int, Kc: int,
     k1_f = (key_f.astype(jnp.int64) << _DT_BITS) | _DT_MAX
     cat_k1 = jnp.concatenate([k1_r, k1_f])
     cat_k2 = jnp.concatenate([k2_r, jnp.zeros((HK,), jnp.int64)])
+    zf32 = jnp.zeros((HK,), jnp.int32)
     zf64 = jnp.zeros((HK,), jnp.int64)
     cat_cols = [
         jnp.concatenate([pool.time, jnp.full((HK,), NEVER, jnp.int64)]),
-        cat_k2,
         # TRUE dst rides along (fillers carry their host id)
-        jnp.concatenate([_pack2(pool.dst, pool.kind),
-                         _pack2(key_f, jnp.zeros((HK,), jnp.int32))]),
+        jnp.concatenate([pool.dst, key_f]),
+        jnp.concatenate([pool.src, zf32]),
+        jnp.concatenate([pool.seq, zf32]),
+        jnp.concatenate([pool.kind, zf32]),
     ] + [jnp.concatenate([pool.payload[:, w], zf64]) for w in range(PP)]
     iota = jnp.arange(N, dtype=jnp.int32)
     # the row index as the last key orders ties as a stable sort would
@@ -487,10 +507,7 @@ def _dense_extract(pool: EventPool, win_start, win_end, H: int, Kc: int,
     slot = jnp.where(extract, s_key * Kc + rank, jnp.int32(N))
     # slot, then the first sort's order: one packed key (both < 2^31)
     _, perm = jax.lax.sort([_pack2(slot, iota), perm1], num_keys=1)
-    o_t, o_k2, o_dk, *o_p = _take_rows(cat_cols, perm)
-    o_d, o_k = _unpack2(o_dk)
-    o_s = (o_k2 >> 32).astype(jnp.int32)
-    o_q = o_k2.astype(jnp.int32)  # low 32 bits (seq is nonnegative)
+    o_t, o_d, o_s, o_q, o_k, *o_p = _take_rows(cat_cols, perm)
     d_t = o_t[:HK].reshape(H, Kc)
     d_s = o_s[:HK].reshape(H, Kc)
     d_q = o_q[:HK].reshape(H, Kc)
@@ -737,11 +754,9 @@ def make_window_step(
                 s_t, perm = jax.lax.sort([m_t, iota], num_keys=2)
                 dropped = jnp.sum(s_t[C:] != NEVER, dtype=jnp.int64)
                 perm = perm[:C]
-                g_dk, g_sq, *g_p = _take_rows(
-                    [_pack2(m_d, m_k), _pack2(m_s, m_q)] + list(m_p), perm
+                g_d, g_s, g_q, g_k, *g_p = _take_rows(
+                    [m_d, m_s, m_q, m_k] + list(m_p), perm
                 )
-                g_d, g_k = _unpack2(g_dk)
-                g_s, g_q = _unpack2(g_sq)
                 new_pool = EventPool(
                     time=s_t[:C], dst=g_d, src=g_s, seq=g_q, kind=g_k,
                     payload=jnp.stack(g_p, axis=-1),
@@ -1259,10 +1274,12 @@ def make_window_step(
             no micro-step loop at all. PHOLD-class models hit this every
             window; it is the PDES "superstep" optimization.
 
-            TPU note (profiled on v5e): large GATHERS serialize (~9 ns per
-            element) while multi-operand sorts and scans run at memory
-            bandwidth, so this path is built from sorts, cumulative scans,
-            and reshapes ONLY (_dense_extract)."""
+            TPU note (profiled on v5e): large GATHERS serialize per
+            index (3-4 ns each; a 64-bit gather makes one pass per 32-bit
+            half, so _take_rows moves 32-bit words), while sorts and scans
+            run at memory bandwidth, so this path is built from sorts,
+            cumulative scans, reshapes and one row gather per permutation
+            (_dense_extract)."""
             with jax.named_scope("win.commit"):
                 state = _obs_win_bump(
                     state, obs_mod.WIN_WINDOWS, obs_mod.WIN_MATRIX

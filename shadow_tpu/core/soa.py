@@ -64,11 +64,11 @@ def pack_words(payload):
     """[..., P] i32 payload words → [..., ceil(P/2)] i64, pairs packed as
     (hi word 2w+1) << 32 | (lo word 2w, zero-extended).
 
-    Every payload word is a column of the engine's window-sort
-    permutation gathers (engine._take_rows); packing halves the column
-    count (and the box-write traffic) at the cost of one elementwise pass
-    at the pack/unpack boundaries. Odd P pads the last high word with
-    zero."""
+    Packing halves the box-write traffic (and the columns the engine
+    carries) at the cost of one elementwise pass at the pack/unpack
+    boundaries. It does not set the row gathers' cost: engine._take_rows
+    moves every row as 32-bit words, packed or not. Odd P pads the last
+    high word with zero."""
     P = payload.shape[-1]
     if P % 2:
         payload = jnp.concatenate(

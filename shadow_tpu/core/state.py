@@ -53,9 +53,8 @@ class EventPool:
     like the reference's per-source event ID.
 
     Payload words are stored PACKED, two i32 words per i64 column
-    (core.soa.pack_words): every payload column follows the engine's
-    window sorts through a row gather, and packing halves that column
-    count.
+    (core.soa.pack_words), halving the box-write traffic; the engine's
+    row gather moves them as 32-bit words either way (engine._take_rows).
     Handlers always see the unpacked [H, P] i32 view via EventView.
     """
 

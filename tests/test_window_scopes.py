@@ -1,7 +1,9 @@
 """The window kernel's phases as named scopes in the traced `run_to`
 program (the op_name a profiler shows for each op): each of `win.extract`,
 `win.handlers`, `win.commit` and `win.merge` holds ops, and the sorts and
-the `_take_rows` gathers lie under extraction or the merge."""
+the `_take_rows` gathers lie under extraction or the merge; and no row
+gather moves 64-bit elements, which the TPU gathers one half-word at a
+time."""
 
 from __future__ import annotations
 
@@ -48,13 +50,13 @@ BUILDS = {
 
 
 def _leaf_ops(jaxpr, stack=()):
-    """(primitive, name-stack components) of every equation that holds no
+    """(equation, name-stack components) of every equation that holds no
     sub-program, with the stacks of the equations that hold it."""
     for eqn in jaxpr.eqns:
         here = stack + tuple(str(eqn.source_info.name_stack).split("/"))
         subs = list(jax.core.jaxprs_in_params(eqn.params))
         if not subs:
-            yield eqn.primitive.name, here
+            yield eqn, here
         for sub in subs:
             yield from _leaf_ops(sub, here)
 
@@ -97,9 +99,22 @@ def test_each_phase_names_ops(run_to_ops):
 
 def test_sorts_and_row_gathers_lie_in_extract_or_merge(run_to_ops):
     name, ops = run_to_ops
-    sorts = [_phase(s) for prim, s in ops if prim == "sort"]
-    rows = [_phase(s) for prim, s in ops
-            if prim == "gather" and TAKE_ROWS in s]
+    sorts = [_phase(s) for e, s in ops if e.primitive.name == "sort"]
+    rows = [_phase(s) for e, s in ops
+            if e.primitive.name == "gather" and TAKE_ROWS in s]
     assert len(sorts) >= 3 and len(rows) >= 2, (name, sorts, rows)
     assert set(sorts) | set(rows) <= {"win.extract", "win.merge"}
     assert {"win.extract", "win.merge"} <= set(sorts)
+
+
+def test_row_gathers_move_32_bit_words(run_to_ops):
+    """Every row gather (operand of rank >= 2) moves 32-bit elements: the
+    TPU lowers a 64-bit gather as one index pass per half-word, so
+    `_take_rows` gathers its rows as int32 words. 1-D table lookups are
+    not row moves."""
+    name, ops = run_to_ops
+    rows = [e.invars[0].aval for e, _ in ops
+            if e.primitive.name == "gather" and e.invars[0].aval.ndim >= 2]
+    assert rows, name
+    wide = [a.str_short() for a in rows if a.dtype.itemsize > 4]
+    assert not wide, (name, wide)
