@@ -14,16 +14,10 @@ limit is 0. Three numbers are compared:
 
 from __future__ import annotations
 
-import importlib
-
 import numpy as np
 
 LIMITS = {"host_values_off": 0, "pending_off": 0, "totals_off": 0}
 INT64_MAX = np.iinfo(np.int64).max
-
-
-def reference_for(app_model: str):
-    return importlib.import_module(f"benchmark.reference.{app_model}")
 
 
 def _get(obj, path: str):
@@ -40,6 +34,13 @@ def _read(state, expr: str) -> np.ndarray:
         v = v - _get(state, b).astype(np.int64)
     # per-host tables with a slot axis ([H, sockets]) compare as host sums
     return v.reshape(v.shape[0], -1).sum(axis=1) if v.ndim > 1 else v
+
+
+def capacity(state, ref) -> dict:
+    """The reference's CAPACITY counters in the program's state, each
+    summed over hosts. Reads those leaves alone, and nothing where the
+    reference declares none."""
+    return {p: int(np.sum(_read(state, p))) for p in ref.CAPACITY}
 
 
 def observe(state, ref) -> dict:

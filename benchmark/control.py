@@ -1,8 +1,9 @@
-"""The control of the comparison: the plain reference put in the program's
-place with its random draws in bfloat16, the precision below the float32
-the program draws in (a destination, a loss roll). It must come out as
-not correct, so the comparison is shown to tell a different run from a
-sound one. The benchmark's own runs do not run it.
+"""The control of the comparison: the plain reference's `control`, its
+prediction made a step below the program's stated precision or guarantee
+(PHOLD: its random draws in bfloat16, below the float32 the program
+draws in), put in the program's place. It must come out as not correct,
+so the comparison is shown to tell a different run from a sound one. The
+benchmark's own runs do not run it.
 
     python3 -m benchmark.control --workload <cell> --seeds 1,2,3 --frontier-s 60
 
@@ -16,8 +17,6 @@ from __future__ import annotations
 import argparse
 import json
 
-import jax.numpy as jnp
-
 from benchmark import compare, spec as spec_mod, traffic
 from benchmark.run import _app_model
 
@@ -27,9 +26,9 @@ def readings(cell_name: str, seed: int, frontier_ns: int,
     spec = spec or spec_mod.Spec.load()
     cell = spec.cell(cell_name)
     cfg = traffic.apply(spec.config(cell), spec.traffic(cell), seed)
-    ref = compare.reference_for(_app_model(cfg))
+    ref = spec.reference(_app_model(cfg))
     sound = ref.expected(cfg, frontier_ns)
-    control = ref.expected(cfg, frontier_ns, jnp.bfloat16)
+    control = ref.control(cfg, frontier_ns)
     return {"seed": seed, "frontier_ns": frontier_ns,
             "control": compare.compare(control, sound, ref),
             "sound": compare.compare(sound, sound, ref)}
@@ -44,8 +43,10 @@ def main(argv: list[str] | None = None) -> int:
     import jax
 
     print(f"# {jax.devices()[0].device_kind}", flush=True)
+    spec = spec_mod.Spec.load()
     for seed in (int(s) for s in args.seeds.split(",")):
-        out = readings(args.workload, seed, int(args.frontier_s * 1e9))
+        out = readings(args.workload, seed, int(args.frontier_s * 1e9),
+                       spec)
         print(json.dumps(out), flush=True)
     return 0
 

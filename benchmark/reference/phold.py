@@ -21,12 +21,16 @@ each message derives its host's key itself rather than gathering it.
 from __future__ import annotations
 
 import functools
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.reference import common
+from benchmark.spec import load_module
+
+# this tree's common.py, as Spec.reference loads this file by its path
+common = load_module(Path(__file__).with_name("common.py"))
 
 # where the program under test keeps what the reference predicts
 FIELDS = {
@@ -39,6 +43,8 @@ TOTALS = {
     "packets_sent": "counters.packets_sent",
     "packets_dropped_loss": "counters.packets_dropped_loss",
 }
+# PHOLD has no bounded ring beyond the engine's pool and outbox
+CAPACITY = ()
 
 
 @functools.partial(jax.jit, static_argnums=(0, 10))
@@ -115,3 +121,9 @@ def expected(cfg: dict, frontier_ns: int, dtype=jnp.float32) -> dict:
         out["packets_sent"] + H * msgload - out["events_committed"]
         - out["pending"].sum())
     return out
+
+
+def control(cfg: dict, frontier_ns: int) -> dict:
+    """The reference with its draws (destination, loss roll) in bfloat16,
+    the precision below the float32 the program draws in."""
+    return expected(cfg, frontier_ns, jnp.bfloat16)

@@ -191,14 +191,19 @@ def _app_model(cfg: dict) -> str:
     return model
 
 
-def operations(before: dict, after: dict) -> tuple[int, int]:
-    """(attempted, failed) over the window from the engine's counters:
-    events committed plus those dropped for capacity, and the drops. The
-    model's own packet loss is a result, and a deferral is no failure."""
+def operations(before: dict, after: dict, cap_before: dict,
+               cap_after: dict) -> tuple[int, int]:
+    """(attempted, failed) over the window: events committed plus those
+    dropped for capacity, and the drops. The drops are the engine's pool
+    and outbox drops (its counters `before` and `after`) and the growth
+    of the reference's CAPACITY counters (`compare.capacity` at each
+    end). The model's own packet loss is a result, and a deferral is no
+    failure."""
     def d(key):
         return int(after[key]) - int(before[key])
 
-    failed = d("pool_overflow_dropped") + d("outbox_overflow_dropped")
+    failed = (d("pool_overflow_dropped") + d("outbox_overflow_dropped")
+              + sum(cap_after[k] - cap_before[k] for k in cap_after))
     return d("events_committed") + failed, failed
 
 
@@ -216,7 +221,7 @@ def measure(cell_name: str, seed: int, seconds: float, trace: bool,
     cell = spec.cell(cell_name)
     mix = spec.traffic(cell)
     cfg = traffic.apply(spec.config(cell), mix, seed)
-    ref = compare.reference_for(_app_model(cfg))
+    ref = spec.reference(_app_model(cfg))
     cache = spec.root / CACHE
     # the program takes the cache the benchmark gives it
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(cache / "jax")
@@ -239,6 +244,7 @@ def measure(cell_name: str, seed: int, seconds: float, trace: bool,
     sim.run(until=traffic.warm_until_ns(mix), windows_per_dispatch=wpd)
     jax.block_until_ready(sim.state)
     before = sim.counters()
+    cap_before = compare.capacity(sim.state, ref)
     f0 = win.warm[-1]
     programs0 = clock.programs
     compile_s = clock.total_s
@@ -262,6 +268,7 @@ def measure(cell_name: str, seed: int, seconds: float, trace: bool,
     wall = end.t - win.t0
     window_programs = clock.programs - programs0
     after = sim.counters()
+    cap_after = compare.capacity(sim.state, ref)
     stats = dev.memory_stats() or {}
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devs),
@@ -270,7 +277,7 @@ def measure(cell_name: str, seed: int, seconds: float, trace: bool,
     sim.obs_session = None
     del sim
     gc.collect()
-    attempted, failed = operations(before, after)
+    attempted, failed = operations(before, after, cap_before, cap_after)
     out = {
         "correct": False,
         "attempted": attempted,

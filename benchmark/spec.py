@@ -4,7 +4,9 @@
                                   `reduced` and `assumed` beside it
     traffic/<config>.<mix>.json   the traffic mix (traffic.py reads it)
     layers/<metric>.py            one reader per per-layer metric
-    reference/<app model>.py      the plain reference of an app model
+    reference/<app model>.py      the plain reference of an app model,
+                                  to the protocol of reference/__init__.py
+    tables/event_bytes/<config>.json  bytes one committed event moves
 
 A later cell, mix, configuration or metric is new files and new entries;
 nothing here changes.
@@ -31,6 +33,7 @@ class Spec:
         self.doc = doc
         self.root = Path(root)
         self.bench = self.root / doc["paths"][0]
+        self._references: dict = {}
 
     @classmethod
     def load(cls, root: Path = ROOT) -> "Spec":
@@ -72,15 +75,31 @@ class Spec:
 
     def reader(self, metric: str):
         """The `read(run)` function of layers/<metric>.py."""
-        path = self.bench / "layers" / f"{metric}.py"
-        if not path.is_file():
-            raise SpecError(f"per-layer metric {metric!r}: no reader {path}")
-        spec = importlib.util.spec_from_file_location(
-            "benchmark_layer_" + metric.replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load_module(self.bench / "layers" / f"{metric}.py",
+                           f"per-layer metric {metric!r}").read
+
+    def reference(self, app_model: str):
+        """The module reference/<app model>.py of this tree, loaded once
+        per Spec (its jitted programs then trace once)."""
+        if app_model not in self._references:
+            self._references[app_model] = load_module(
+                self.bench / "reference" / f"{app_model}.py",
+                f"app model {app_model!r}")
+        return self._references[app_model]
 
     def table(self, name: str) -> dict:
         with open(self.bench / "tables" / f"{name}.json") as f:
             return json.load(f)
+
+
+def load_module(path: Path, what: str = "module"):
+    """A module of a benchmark tree, by its file path, so that the tree's
+    own file runs and not the importable package's."""
+    path = Path(path)
+    if not path.is_file():
+        raise SpecError(f"{what}: no file {path}")
+    name = f"benchmark_{path.parent.name}_{path.stem}".replace(".", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

@@ -1,19 +1,23 @@
 """`correct` through the whole harness at a tiny size on the CPU: true on
 sound runs, false with the timed path broken underneath, and false for
-the control (the reference with its draws in bfloat16)."""
+the control (the reference's `control`). Every cell of BENCHMARK.json
+runs; faults particular to an app model come from faults/<app>.py."""
 
 from __future__ import annotations
 
 import json
+import time
 
 import jax.numpy as jnp
 import pytest
 
-from benchmark import compare, control, traffic
+from benchmark import compare, control, run, traffic
 from benchmark.spec import Spec
-from benchmark.tests.conftest import copy_bench, shrink
+from benchmark.tests.conftest import (app_model, cells, copy_bench, faults,
+                                      shrink)
 
-CELLS = ["phold_16k.msgload8", "phold_16k.msgload1"]
+CELLS = cells()
+PHOLD_CELLS = [c for c in CELLS if app_model(c) == "phold"]
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -23,6 +27,27 @@ def test_sound_run_is_correct(measure_tiny, cell):
     assert out["failed"] == 0 and out["attempted"] > 0
     assert list(out)[-1] == "checks"
     assert out["metrics"]["sim_s_per_wall_s"]["value"] > 0
+
+
+def test_phold_operations_are_the_engine_counts(measure_tiny, monkeypatch):
+    """PHOLD declares no CAPACITY counter: `attempted` and `failed` are
+    the window's events committed plus pool and outbox drops, and those
+    drops, as read from the engine's counters alone."""
+    seen = []
+    real = run.operations
+
+    def spy(before, after, cap_before, cap_after):
+        seen.append((before, after, cap_before, cap_after))
+        return real(before, after, cap_before, cap_after)
+
+    monkeypatch.setattr(run, "operations", spy)
+    out = measure_tiny("phold_16k.msgload8")
+    ((before, after, cap_before, cap_after),) = seen
+    assert cap_before == cap_after == {}
+    d = {k: after[k] - before[k] for k in after}
+    dropped = d["pool_overflow_dropped"] + d["outbox_overflow_dropped"]
+    assert out["failed"] == dropped == 0
+    assert out["attempted"] == d["events_committed"] + dropped > 0
 
 
 def _state_unchanged(monkeypatch):
@@ -48,44 +73,16 @@ def _state_unchanged(monkeypatch):
     monkeypatch.setattr(engine, "make_run_to", make)
 
 
-def _half_batch(monkeypatch):
-    """Events of odd hosts are taken from the pool and never handled."""
-    from shadow_tpu.net import apps
-
-    real_m = apps.PholdApp.handle_msg_matrix
-
-    def matrix(self, state, mv, emitter, params):
-        even = (state.host.gid % 2 == 0)[:, None]
-        return real_m(self, state, mv.replace(mask=mv.mask & even),
-                      emitter, params)
-
-    monkeypatch.setattr(apps.PholdApp, "handle_msg_matrix", matrix)
+def _faults(cell: str) -> dict:
+    """Every cell's faults: the generic one and its app model's own."""
+    return {"state_unchanged": _state_unchanged, **faults(app_model(cell))}
 
 
-def _answer_altered(monkeypatch):
-    """A message goes to the next host over from where its sender's draw
-    says."""
-    from shadow_tpu.net import apps
-
-    real_pick = apps.PholdApp._pick_dst
-
-    def pick(self, u, my_id):
-        d = real_pick(self, u, my_id)
-        nxt = (d + 1) % self.num_hosts
-        return jnp.where(nxt == my_id, d, nxt)
-
-    monkeypatch.setattr(apps.PholdApp, "_pick_dst", pick)
-
-
-FAULTS = {"state_unchanged": _state_unchanged, "half_batch": _half_batch,
-          "answer_altered": _answer_altered}
-
-
-@pytest.mark.parametrize("fault", sorted(FAULTS))
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell,fault", [
+    (cell, fault) for cell in CELLS for fault in sorted(_faults(cell))])
 def test_broken_timed_path_is_not_correct(measure_tiny, monkeypatch, cell,
                                           fault):
-    FAULTS[fault](monkeypatch)
+    _faults(cell)[fault](monkeypatch)
     out = measure_tiny(cell)
     assert not out["correct"], out["checks"]
 
@@ -101,22 +98,20 @@ def test_control_is_not_correct(tiny_root, cell):
         assert not compare.passed(r["control"]), r
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", PHOLD_CELLS)
 def test_loss_rolls_match(tmp_path, cell):
     """At a loss rate where many messages drop, the reference still
     predicts every host exactly."""
     copy_bench(tmp_path)
-    shrink(tmp_path, "phold_16k", 256, 4096)
-    path = tmp_path / "benchmark" / "configs" / "phold_16k.json"
+    sp = Spec.load(tmp_path)
+    entry = sp.config_entry(sp.cell(cell))
+    shrink(tmp_path, entry["name"])
+    path = tmp_path / entry["file"]
     cfg = json.loads(path.read_text())
     g = cfg["network"]["graph"]
     g["inline"] = g["inline"].replace("packet_loss 0.0", "packet_loss 0.002")
     path.write_text(json.dumps(cfg))
-    import time
-
     import jax
-
-    from benchmark import run
 
     out = run.measure(cell, 77, 1.0, False, time.perf_counter(),
                       spec=Spec.load(tmp_path),

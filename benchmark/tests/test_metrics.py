@@ -25,7 +25,23 @@ def test_operations_count_capacity_drops_only():
     after = {"events_committed": 1100, "pool_overflow_dropped": 4,
              "outbox_overflow_dropped": 2, "packets_dropped_loss": 50,
              "inbox_overflow_deferred": 90}
-    assert run.operations(before, after) == (1005, 5)
+    assert run.operations(before, after, {}, {}) == (1005, 5)
+
+
+def test_operations_count_the_references_capacity_drops():
+    """The growth of each CAPACITY counter over the window is failed, and
+    attempted; the model's own drops (loss, CoDel) are not."""
+    ring, sendq = "subs.router.overflow_dropped", "subs.nic.sendq_dropped"
+    before = {"events_committed": 100, "pool_overflow_dropped": 1,
+              "outbox_overflow_dropped": 0, "packets_dropped_loss": 3,
+              "subs.router.codel_dropped": 5}
+    after = {"events_committed": 1100, "pool_overflow_dropped": 4,
+             "outbox_overflow_dropped": 2, "packets_dropped_loss": 50,
+             "subs.router.codel_dropped": 60}
+    assert run.operations(before, after, {ring: 7, sendq: 0},
+                          {ring: 27, sendq: 11}) == (1036, 36)
+    assert run.operations(before, after, {ring: 7}, {ring: 27}) == (1025, 25)
+    assert run.operations(before, after, {}, {}) == (1005, 5)
 
 
 def _record(**kw):
@@ -70,6 +86,14 @@ def test_readers_find_nothing_without_a_trace():
     for m in ("driver.host_drain_share", "device.idle_share",
               "kernel.ms_per_window", "window_kernel_roofline"):
         assert read(m, rec) is None
+
+
+def test_roofline_needs_its_configurations_row_size():
+    """A configuration with no tables/event_bytes/<config>.json has no
+    roofline to read, rather than one counted from another's rows."""
+    rec = _record(cell={"name": "x.y", "config": "x", "traffic": "y"})
+    assert read("kernel.ms_per_window", rec) == pytest.approx(1e3 / 64)
+    assert read("window_kernel_roofline", rec) is None
 
 
 def test_unknown_device_kind_is_an_error():

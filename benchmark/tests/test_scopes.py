@@ -172,5 +172,6 @@ def test_readers(tmp_path):
     entries = {m["name"]: m for m in sp.doc["per_layer"]}
     for name in NEW + ("driver.fetch_ms_per_dispatch",):
         assert entries[name]["moves"] == "sim_s_per_wall_s"
-        assert entries[name]["workloads"] == ["phold_16k.msgload8",
-                                              "phold_16k.msgload1"]
+        # read from any cell's spans and window kernel, so every cell
+        # reports it, those added later too
+        assert "workloads" not in entries[name]
