@@ -1,0 +1,10 @@
+"""Device milliseconds per simulated window of the NIC's token buckets and
+send ring (net/nic.py): self time of the ops under the `net.nic` named
+scope in whole `run_to` executions (net_scopes.py) over the windows a
+dispatch runs; moves sim_s_per_wall_s."""
+
+from benchmark import net_scopes
+
+
+def read(run):
+    return net_scopes.ms_per_window(run, "net.nic")

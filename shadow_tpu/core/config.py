@@ -505,6 +505,12 @@ class HostOptions:
     # Device-plane CPU model (host/cpu.c analog): simulated processing cost
     # per device event; a loaded host's events serialize on its virtual CPU.
     cpu_ns_per_event: int = 0
+    # Depth of this host's upstream-router ring where it differs from
+    # experimental.router_queue_slots: the hosts that set it share one deep
+    # ring beside the shallow ring of all the others (net/codel.py). The
+    # simulator's bounded stand-in for Shadow's unbounded router queue, for
+    # the few hosts a fan-in loads.
+    router_queue_slots: Optional[int] = None
 
     @classmethod
     def from_dict(cls, name: str, d: dict, defaults: dict) -> "HostOptions":
@@ -514,6 +520,7 @@ class HostOptions:
             "log_level", "pcap_directory", "network_node_id",
             "app_model", "app_options", "heartbeat_interval",
             "heartbeat_log_info", "heartbeat_log_level", "cpu_ns_per_event",
+            "router_queue_slots",
         }
         _check_fields(f"hosts.{name}", d, allowed)
         merged = dict(defaults)
@@ -541,6 +548,12 @@ class HostOptions:
             out.cpu_ns_per_event = units.parse_time_ns(
                 merged["cpu_ns_per_event"], default_unit="ns"
             )
+        if merged.get("router_queue_slots") is not None:
+            out.router_queue_slots = int(merged["router_queue_slots"])
+            if out.router_queue_slots < 1:
+                raise ConfigError(
+                    f"hosts.{name}.router_queue_slots must be >= 1"
+                )
         return out
 
     def expand(self) -> list["HostOptions"]:

@@ -89,9 +89,16 @@ class _LaneFaults:
 def _build_solo(spec: JobSpec):
     """Build one job's solo Simulation (host-side: topology bake + initial
     events; no kernel is ever dispatched on it)."""
-    from shadow_tpu.sim import build_simulation
+    from shadow_tpu.sim import BuildError, build_simulation
 
-    return build_simulation(load_config(spec.config))
+    sim = build_simulation(load_config(spec.config))
+    router = sim.state.subs.get("router")
+    if router is not None and router.deep_rows is not None:
+        raise BuildError(
+            f"job {spec.name!r}: the fleet layout does not carry the deep "
+            f"router ring (hosts.<group>.router_queue_slots)"
+        )
+    return sim
 
 
 def _align_gear(sim, level: int) -> None:

@@ -293,9 +293,13 @@ class UdpFloodApp:
         start_time: int = simtime.NS_PER_SEC,
         stop_sending: int | None = None,
         local_span: int = 0,
+        start_offsets=None,
     ):
         self.num_hosts = num_hosts
         self.server_hosts = list(server_hosts)
+        # [H] ns after start_time at which each client's first send falls
+        # (sim._start_offsets staggers them); None starts all at once
+        self.start_offsets = start_offsets
         self.interval_ns = int(interval_ns)
         self.size_bytes = int(size_bytes)
         # locality-shaped fan-in: clients within local_span ring hops of a
@@ -343,8 +347,10 @@ class UdpFloodApp:
         }
 
     def initial_events(self):
+        off = self.start_offsets
         return [
-            (self.start_time, h, h, KIND_APP_TIMER, [])
+            (self.start_time + (0 if off is None else int(off[h])), h, h,
+             KIND_APP_TIMER, [])
             for h in range(self.num_hosts)
             if int(self._role[h]) == 1
         ]

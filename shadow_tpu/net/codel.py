@@ -19,11 +19,21 @@ Differences from the reference, both deliberate:
   micro-steps instead of one call.
 - The queue is a bounded ring (the reference is unbounded); overflow drops
   are counted separately.
+
+Two rings. Every host has a slot in the shallow ring `[H, Q, P]`, whose
+reads and writes are one-hot selects over the whole ring (soa.py). Hosts
+that a host group gives their own `router_queue_slots` (the few a fan-in
+loads) use a deep ring `[B, Qd, W]` over those B hosts instead: one row
+per packet (payload words, source, enqueue time), written by a scatter
+and read by a gather of B rows, so its cost follows B and not Qd. The
+head, tail and CoDel state stay `[H]`; a deep host behaves exactly as it
+would in a uniform ring of depth Qd.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 from flax import struct
 
 from shadow_tpu.core import simtime, soa
@@ -59,13 +69,35 @@ class RouterState:
     # word is 0 for simulations built without packet_trails)
     drop_trail: jnp.ndarray  # [H] i32
     drop_time: jnp.ndarray  # [H] i64
+    # the deep ring (None when no host is deep): one row per packet,
+    # payload words then source then enqueue time (low, high word)
+    deep_rows: jnp.ndarray | None = None  # [B, Qd, P + 3] i32
+    deep_host: jnp.ndarray | None = None  # [B] i32 host of each deep row
+    deep_of: jnp.ndarray | None = None  # [H] i32 deep row, -1 = shallow
+    # high-water occupancy: the largest q_tail - q_head a deep host reached
+    deep_high_water: jnp.ndarray | None = None  # [] i32
 
 
 def init(num_hosts: int, queue_slots: int = 64,
-         payload_words: int = PAYLOAD_WORDS) -> RouterState:
+         payload_words: int = PAYLOAD_WORDS, deep_hosts=(),
+         deep_slots: int = 0) -> RouterState:
+    """`deep_hosts` (sorted host ids) get the deep ring of `deep_slots`
+    slots each; every other host the shallow ring of `queue_slots`."""
     H, Q = num_hosts, queue_slots
     z64 = lambda: jnp.zeros((H,), jnp.int64)  # noqa: E731
     z32 = lambda: jnp.zeros((H,), jnp.int32)  # noqa: E731
+    deep = {}
+    if len(deep_hosts):
+        B = len(deep_hosts)
+        of = np.full(H, -1, np.int32)
+        of[np.asarray(deep_hosts)] = np.arange(B, dtype=np.int32)
+        deep = dict(
+            deep_rows=jnp.zeros((B, deep_slots, payload_words + 3),
+                                jnp.int32),
+            deep_host=jnp.asarray(deep_hosts, jnp.int32),
+            deep_of=jnp.asarray(of),
+            deep_high_water=jnp.zeros((), jnp.int32),
+        )
     return RouterState(
         q_payload=jnp.zeros((H, Q, payload_words), jnp.int32),
         q_src=jnp.zeros((H, Q), jnp.int32),
@@ -82,28 +114,81 @@ def init(num_hosts: int, queue_slots: int = 64,
         overflow_dropped=jnp.zeros((), jnp.int64),
         drop_trail=z32(),
         drop_time=z64(),
+        **deep,
     )
+
+
+def has_room(router: RouterState):
+    """[H] bool: the host's ring, shallow or deep, has a free slot."""
+    cap = router.q_src.shape[1]
+    if router.deep_rows is not None:
+        cap = jnp.where(router.deep_of >= 0, router.deep_rows.shape[1], cap)
+    return (router.q_tail - router.q_head) < cap
+
+
+def _deep_write(router: RouterState, ok, payload, src, now) -> RouterState:
+    """Append one row per deep host where `ok`: a scatter of B rows (rows
+    not written aim past the ring and are dropped)."""
+    rows, Qd, _ = router.deep_rows.shape
+    b = router.deep_host
+    slot = jnp.where(ok[b], router.q_tail[b] % Qd, Qd)
+    t = now[b]
+    row = jnp.concatenate([
+        payload[b], src[b][:, None].astype(jnp.int32),
+        (t & 0xFFFFFFFF).astype(jnp.uint32).astype(jnp.int32)[:, None],
+        (t >> 32).astype(jnp.int32)[:, None],
+    ], axis=1)
+    deep_rows = router.deep_rows.at[jnp.arange(rows), slot].set(
+        row, mode="drop")
+    return router.replace(deep_rows=deep_rows)
+
+
+def _deep_head(router: RouterState, payload, src, enq_ts):
+    """The head packet of each deep host (a gather of B rows), put in the
+    deep hosts' places of the shallow ring's [H] reads."""
+    rows, Qd, W = router.deep_rows.shape
+    P = W - 3
+    b = router.deep_host
+    row = router.deep_rows[jnp.arange(rows), router.q_head[b] % Qd]
+    # [B] -> [H]: a one-hot select over the B deep rows
+    hit = router.deep_of[:, None] == jnp.arange(rows, dtype=jnp.int32)
+    at = jnp.sum(jnp.where(hit[:, :, None], row[None], 0), axis=1,
+                 dtype=jnp.int32)
+    deep = (router.deep_of >= 0)
+    ts = (at[:, P + 2].astype(jnp.int64) << 32) | (
+        at[:, P + 1].astype(jnp.int64) & 0xFFFFFFFF)
+    return (jnp.where(deep[:, None], at[:, :P], payload),
+            jnp.where(deep, at[:, P], src),
+            jnp.where(deep, ts, enq_ts))
 
 
 def enqueue(router: RouterState, mask, payload, src, now) -> RouterState:
     """router_enqueue (router.c:103-121): append with enqueue timestamp."""
     H, Q = router.q_src.shape
-    room = (router.q_tail - router.q_head) < Q
+    room = has_room(router)
     ok = mask & room
+    now = jnp.broadcast_to(now, (H,)).astype(jnp.int64)
+    src = src.astype(jnp.int32)
+    shallow = ok if router.deep_rows is None else ok & (router.deep_of < 0)
     slot = router.q_tail % Q
     size = pkt.total_bytes(payload).astype(jnp.int64)
-    return router.replace(
-        q_payload=soa.set_at(router.q_payload, ok, slot, payload),
-        q_src=soa.set_at(router.q_src, ok, slot, src.astype(jnp.int32)),
-        q_enq_ts=soa.set_at(
-            router.q_enq_ts, ok, slot,
-            jnp.broadcast_to(now, (H,)).astype(jnp.int64),
-        ),
+    if router.deep_rows is not None:
+        router = _deep_write(router, ok, payload, src, now)
+    router = router.replace(
+        q_payload=soa.set_at(router.q_payload, shallow, slot, payload),
+        q_src=soa.set_at(router.q_src, shallow, slot, src),
+        q_enq_ts=soa.set_at(router.q_enq_ts, shallow, slot, now),
         q_tail=router.q_tail + ok.astype(jnp.int32),
         total_size=router.total_size + jnp.where(ok, size, 0),
         overflow_dropped=router.overflow_dropped
         + jnp.sum(mask & ~room, dtype=jnp.int64),
     )
+    if router.deep_rows is not None:
+        b = router.deep_host
+        router = router.replace(deep_high_water=jnp.maximum(
+            router.deep_high_water,
+            jnp.max(router.q_tail[b] - router.q_head[b])))
+    return router
 
 
 def _record_drop(router: RouterState, mask, payload, now):
@@ -147,6 +232,8 @@ def _pop_helper(router: RouterState, now, want):
     payload = soa.get_at(router.q_payload, slot)
     src = soa.get_at(router.q_src, slot)
     enq_ts = soa.get_at(router.q_enq_ts, slot)
+    if router.deep_rows is not None:
+        payload, src, enq_ts = _deep_head(router, payload, src, enq_ts)
 
     size = pkt.total_bytes(payload).astype(jnp.int64)
     new_total = jnp.where(have, router.total_size - size, router.total_size)

@@ -14,12 +14,18 @@ the reference's loopback interface which has no upstream router
 
 Event kinds used: KIND_PKT_DELIVER, KIND_NIC_SEND (send pump),
 KIND_NIC_REFILL is reused as the receive pump kind (KIND_NIC_RECV alias).
+
+Inside the window kernel's `win.handlers`, three `jax.named_scope`s name
+the layers (metadata only): `net.nic` (token buckets and the NIC send
+ring), `net.router` (the router rings and CoDel), `net.udp` (socket
+demux, delivery and counts).
 """
 
 from __future__ import annotations
 
 from typing import Callable
 
+import jax
 import jax.numpy as jnp
 
 from shadow_tpu.core.engine import Emitter, EventView
@@ -38,6 +44,8 @@ KIND_NIC_RECV = KIND_NIC_REFILL
 # hook(state, mask, slot, src_host, payload, emitter, now, params) -> state
 RecvHook = Callable
 
+NIC_SCOPE, ROUTER_SCOPE, UDP_SCOPE = "net.nic", "net.router", "net.udp"
+
 
 class NetStack:
     def __init__(
@@ -55,6 +63,8 @@ class NetStack:
         router_variant: str = "codel",
         payload_words: int = 12,
         discipline: qdisc_mod.Discipline | None = None,
+        deep_hosts=(),
+        deep_queue_slots: int = 0,
     ):
         # Egress scheduling plane: either a legacy string ("fifo" /
         # "roundrobin" — the ring-wrapping disciplines) or a prebuilt
@@ -80,8 +90,10 @@ class NetStack:
             bw_up_bits, bw_down_bits, nic_queue_slots,
             payload_words=payload_words,
         )
+        # hosts a host group gives a deeper router ring share the deep ring
         self._init_router = codel.init(
-            num_hosts, router_queue_slots, payload_words=payload_words
+            num_hosts, router_queue_slots, payload_words=payload_words,
+            deep_hosts=deep_hosts, deep_slots=deep_queue_slots,
         )
         self._init_udp = udp.init(num_hosts, sockets_per_host)
         # UDP-only sims skip the TCP state machine entirely: its handlers
@@ -159,29 +171,30 @@ class NetStack:
         now64 = jnp.broadcast_to(now, (H,)).astype(jnp.int64)
         direct = jnp.zeros((H,), bool)
         if params is not None:
-            # empty-queue test BEFORE any mutation: the refill touches
-            # only the token bucket, never the queue plane
-            queued_any = self.disc.nonempty(state)
-            tx_rem, tx_tick = nic.lazy_refill(
-                n.tx_rem, n.tx_tick, n.tx_refill, n.tx_cap, now64, mask
-            )
-            n = n.replace(tx_rem=tx_rem, tx_tick=tx_tick)
-            size = pkt.total_bytes(payload).astype(jnp.int64)
-            bootstrap = now64 < params.bootstrap_end
-            # same admission gate as the send pump (rem >= MTU, full size
-            # charged, debt allowed) so a packet's timing never depends on
-            # which path carried it
-            direct = mask & ~queued_any & (
-                bootstrap | (n.tx_rem >= pkt.MTU)
-            )
-            # bootstrap sends are free, exactly like the pump path
-            n = n.replace(
-                tx_rem=jnp.where(direct & ~bootstrap, n.tx_rem - size,
-                                 n.tx_rem)
-            )
-            n = nic.count_tx(n, direct, size)
-            state = state.with_sub(nic.SUB, n)
-            state = self.disc.note_direct(state, direct, payload)
+            with jax.named_scope(NIC_SCOPE):
+                # empty-queue test BEFORE any mutation: the refill touches
+                # only the token bucket, never the queue plane
+                queued_any = self.disc.nonempty(state)
+                tx_rem, tx_tick = nic.lazy_refill(
+                    n.tx_rem, n.tx_tick, n.tx_refill, n.tx_cap, now64, mask
+                )
+                n = n.replace(tx_rem=tx_rem, tx_tick=tx_tick)
+                size = pkt.total_bytes(payload).astype(jnp.int64)
+                bootstrap = now64 < params.bootstrap_end
+                # same admission gate as the send pump (rem >= MTU, full
+                # size charged, debt allowed) so a packet's timing never
+                # depends on which path carried it
+                direct = mask & ~queued_any & (
+                    bootstrap | (n.tx_rem >= pkt.MTU)
+                )
+                # bootstrap sends are free, exactly like the pump path
+                n = n.replace(
+                    tx_rem=jnp.where(direct & ~bootstrap, n.tx_rem - size,
+                                     n.tx_rem)
+                )
+                n = nic.count_tx(n, direct, size)
+                state = state.with_sub(nic.SUB, n)
+                state = self.disc.note_direct(state, direct, payload)
             remote = direct & (dst_host != hosts)
             wire = pkt.stamp(payload, direct, pkt.PDS_SENT)
             state = link.send(
@@ -195,10 +208,11 @@ class NetStack:
                          wire)
 
         enq = mask & ~direct
-        state, ok = self.disc.enqueue(
-            state, enq, dst_host.astype(jnp.int32),
-            pkt.stamp(payload, enq, pkt.PDS_NIC_QUEUED), now64,
-        )
+        with jax.named_scope(NIC_SCOPE):
+            state, ok = self.disc.enqueue(
+                state, enq, dst_host.astype(jnp.int32),
+                pkt.stamp(payload, enq, pkt.PDS_NIC_QUEUED), now64,
+            )
         state = pds_mod.record_drop(
             state, enq & ~ok, payload, pkt.PDS_DROPPED_SENDQ, now64
         )
@@ -234,36 +248,44 @@ class NetStack:
         hosts = state.host.gid
         H = hosts.shape[0]
         if payload is None:
-            payload = pkt.make_udp(
-                src_port=jnp.broadcast_to(jnp.asarray(src_port, jnp.int32), (H,)),
-                dst_port=jnp.broadcast_to(jnp.asarray(dst_port, jnp.int32), (H,)),
-                length=jnp.broadcast_to(jnp.asarray(size_bytes, jnp.int32), (H,)),
-                priority=jnp.zeros((H,), jnp.int32),
-                src_host=hosts,
-                socket_slot=jnp.broadcast_to(
-                    jnp.asarray(socket_slot, jnp.int32), (H,)
-                ),
-                payload_words=self.payload_words,
-            )
+            with jax.named_scope(UDP_SCOPE):
+                payload = pkt.make_udp(
+                    src_port=jnp.broadcast_to(
+                        jnp.asarray(src_port, jnp.int32), (H,)),
+                    dst_port=jnp.broadcast_to(
+                        jnp.asarray(dst_port, jnp.int32), (H,)),
+                    length=jnp.broadcast_to(
+                        jnp.asarray(size_bytes, jnp.int32), (H,)),
+                    priority=jnp.zeros((H,), jnp.int32),
+                    src_host=hosts,
+                    socket_slot=jnp.broadcast_to(
+                        jnp.asarray(socket_slot, jnp.int32), (H,)
+                    ),
+                    payload_words=self.payload_words,
+                )
         state, ok = self._tx(state, emitter, mask, now, dst_host, payload,
                              params=params)
-        u = udp.count_sent(
-            state.subs[udp.SUB], ok,
-            jnp.broadcast_to(jnp.asarray(socket_slot, jnp.int32), (H,)), payload,
-        )
+        with jax.named_scope(UDP_SCOPE):
+            u = udp.count_sent(
+                state.subs[udp.SUB], ok,
+                jnp.broadcast_to(jnp.asarray(socket_slot, jnp.int32), (H,)),
+                payload,
+            )
         return state.with_sub(udp.SUB, u)
 
     # ---- engine handlers ----
 
     def _deliver_local(self, state, mask, src, payload, emitter, now, params):
         """Demux + deliver + app hooks for packets that reached the NIC."""
-        u = state.subs[udp.SUB]
-        is_udp = mask & (payload[:, pkt.W_PROTO] == pkt.PROTO_UDP)
-        slot, found = udp.demux(u, is_udp, payload, src)
-        u = udp.deliver(u, found, slot, payload)
-        u = u.replace(
-            drop_no_socket=u.drop_no_socket + jnp.sum(is_udp & ~found, dtype=jnp.int64)
-        )
+        with jax.named_scope(UDP_SCOPE):
+            u = state.subs[udp.SUB]
+            is_udp = mask & (payload[:, pkt.W_PROTO] == pkt.PROTO_UDP)
+            slot, found = udp.demux(u, is_udp, payload, src)
+            u = udp.deliver(u, found, slot, payload)
+            u = u.replace(
+                drop_no_socket=u.drop_no_socket
+                + jnp.sum(is_udp & ~found, dtype=jnp.int64)
+            )
         c = state.counters
         state = state.replace(
             counters=c.replace(
@@ -274,12 +296,13 @@ class NetStack:
                 ),
             )
         )
-        state = state.with_sub(
-            nic.SUB,
-            nic.count_rx(
-                state.subs[nic.SUB], mask, pkt.total_bytes(payload)
-            ),
-        )
+        with jax.named_scope(NIC_SCOPE):
+            state = state.with_sub(
+                nic.SUB,
+                nic.count_rx(
+                    state.subs[nic.SUB], mask, pkt.total_bytes(payload)
+                ),
+            )
         state = state.with_sub(udp.SUB, u)
         state = pds_mod.record_delivery(state, mask, payload, now)
         for hook in self.recv_hooks:
@@ -309,37 +332,39 @@ class NetStack:
 
         n = state.subs[nic.SUB]
         r = state.subs[codel.SUB]
-        rx_rem, rx_tick = nic.lazy_refill(
-            n.rx_rem, n.rx_tick, n.rx_refill, n.rx_cap, now, remote
-        )
-        n = n.replace(rx_rem=rx_rem, rx_tick=rx_tick)
-        bootstrap = now < params.bootstrap_end
-        size = pkt.total_bytes(ev.payload).astype(jnp.int64)
-        direct = (
-            remote & ~codel.nonempty(r)
-            & (bootstrap | (n.rx_rem >= pkt.MTU))
-        )
-        n = n.replace(
-            rx_rem=jnp.where(direct & ~bootstrap, n.rx_rem - size, n.rx_rem)
-        )
-        # zero-sojourn dequeue semantics: good packet → interval reset,
-        # drop-mode exit (codel.dequeue with sojourn 0 does exactly this)
-        r = r.replace(
-            interval_expire=jnp.where(direct, 0, r.interval_expire),
-            drop_mode=jnp.where(direct, False, r.drop_mode),
-        )
-
+        with jax.named_scope(ROUTER_SCOPE):
+            empty = ~codel.nonempty(r)
+        with jax.named_scope(NIC_SCOPE):
+            rx_rem, rx_tick = nic.lazy_refill(
+                n.rx_rem, n.rx_tick, n.rx_refill, n.rx_cap, now, remote
+            )
+            n = n.replace(rx_rem=rx_rem, rx_tick=rx_tick)
+            bootstrap = now < params.bootstrap_end
+            size = pkt.total_bytes(ev.payload).astype(jnp.int64)
+            direct = remote & empty & (bootstrap | (n.rx_rem >= pkt.MTU))
+            n = n.replace(
+                rx_rem=jnp.where(direct & ~bootstrap, n.rx_rem - size,
+                                 n.rx_rem)
+            )
         queued = remote & ~direct
-        no_room = queued & ~(
-            (r.q_tail - r.q_head) < r.q_src.shape[1]
-        )
+        with jax.named_scope(ROUTER_SCOPE):
+            # zero-sojourn dequeue semantics: good packet → interval reset,
+            # drop-mode exit (codel.dequeue with sojourn 0 does exactly
+            # this)
+            r = r.replace(
+                interval_expire=jnp.where(direct, 0, r.interval_expire),
+                drop_mode=jnp.where(direct, False, r.drop_mode),
+            )
+            no_room = queued & ~codel.has_room(r)
         state = pds_mod.record_drop(
             state, no_room, ev.payload, pkt.PDS_DROPPED_OVERFLOW, now
         )
-        r = codel.enqueue(
-            r, queued, pkt.stamp(ev.payload, queued, pkt.PDS_ROUTER_ENQUEUED),
-            ev.src, now,
-        )
+        with jax.named_scope(ROUTER_SCOPE):
+            r = codel.enqueue(
+                r, queued,
+                pkt.stamp(ev.payload, queued, pkt.PDS_ROUTER_ENQUEUED),
+                ev.src, now,
+            )
         state = state.with_sub(codel.SUB, r).with_sub(nic.SUB, n)
 
         state = self._deliver_local(
@@ -375,33 +400,37 @@ class NetStack:
         n = state.subs[nic.SUB]
         n = n.replace(send_pending=n.send_pending & ~mask)
 
-        tx_rem, tx_tick = nic.lazy_refill(
-            n.tx_rem, n.tx_tick, n.tx_refill, n.tx_cap, now, mask
-        )
-        n = n.replace(tx_rem=tx_rem, tx_tick=tx_tick)
+        with jax.named_scope(NIC_SCOPE):
+            tx_rem, tx_tick = nic.lazy_refill(
+                n.tx_rem, n.tx_tick, n.tx_refill, n.tx_cap, now, mask
+            )
+            n = n.replace(tx_rem=tx_rem, tx_tick=tx_tick)
         bootstrap = now < params.bootstrap_end
         state = state.with_sub(nic.SUB, n)
 
         for _ in range(self.PUMP_BATCH):
-            n = state.subs[nic.SUB]
-            can = bootstrap | (n.tx_rem >= pkt.MTU)
-            want = mask & can
-            # the discipline owns head selection, the pop, AND any
-            # dequeue-side drop policy (codel hook) — `do` marks hosts
-            # that produced a deliverable packet this round
-            state, do, payload, dst = self.disc.dequeue(state, now, want)
+            with jax.named_scope(NIC_SCOPE):
+                n = state.subs[nic.SUB]
+                can = bootstrap | (n.tx_rem >= pkt.MTU)
+                want = mask & can
+                # the discipline owns head selection, the pop, AND any
+                # dequeue-side drop policy (codel hook) — `do` marks hosts
+                # that produced a deliverable packet this round
+                state, do, payload, dst = self.disc.dequeue(state, now, want)
 
-            # Charge the FULL wire size (may go negative — token debt). For
-            # MTU-conformant packets this is identical to the reference's
-            # clamp-at-zero (rem ≥ MTU ≥ size when the gate passes); for
-            # oversize packets debt prevents exceeding configured bandwidth.
-            size = pkt.total_bytes(payload).astype(jnp.int64)
-            n = state.subs[nic.SUB]
-            n = n.replace(
-                tx_rem=jnp.where(do & ~bootstrap, n.tx_rem - size, n.tx_rem)
-            )
-            n = nic.count_tx(n, do, size)
-            state = state.with_sub(nic.SUB, n)
+                # Charge the FULL wire size (may go negative — token debt).
+                # For MTU-conformant packets this is identical to the
+                # reference's clamp-at-zero (rem ≥ MTU ≥ size when the gate
+                # passes); for oversize packets debt prevents exceeding
+                # configured bandwidth.
+                size = pkt.total_bytes(payload).astype(jnp.int64)
+                n = state.subs[nic.SUB]
+                n = n.replace(
+                    tx_rem=jnp.where(do & ~bootstrap, n.tx_rem - size,
+                                     n.tx_rem)
+                )
+                n = nic.count_tx(n, do, size)
+                state = state.with_sub(nic.SUB, n)
 
             remote = do & (dst != hosts)
             wire = pkt.stamp(payload, do, pkt.PDS_SENT)
@@ -414,7 +443,8 @@ class NetStack:
             lb = do & (dst == hosts)
             emitter.emit(lb, now, hosts, jnp.int32(KIND_PKT_DELIVER), wire)
 
-        still = self.disc.nonempty(state)
+        with jax.named_scope(NIC_SCOPE):
+            still = self.disc.nonempty(state)
         n = state.subs[nic.SUB]
         need = mask & still
         can_next = bootstrap | (n.tx_rem >= pkt.MTU)
@@ -438,24 +468,28 @@ class NetStack:
         n = state.subs[nic.SUB]
         n = n.replace(recv_pending=n.recv_pending & ~mask)
 
-        rx_rem, rx_tick = nic.lazy_refill(
-            n.rx_rem, n.rx_tick, n.rx_refill, n.rx_cap, now, mask
-        )
-        n = n.replace(rx_rem=rx_rem, rx_tick=rx_tick)
+        with jax.named_scope(NIC_SCOPE):
+            rx_rem, rx_tick = nic.lazy_refill(
+                n.rx_rem, n.rx_tick, n.rx_refill, n.rx_cap, now, mask
+            )
+            n = n.replace(rx_rem=rx_rem, rx_tick=rx_tick)
         bootstrap = now < params.bootstrap_end
 
         for _ in range(self.recv_batch):
             can = bootstrap | (n.rx_rem >= pkt.MTU)
             want = mask & can
 
-            r = state.subs[codel.SUB]
-            r, have, payload, src = codel.dequeue(
-                r, now, want, aqm=self.router_aqm
-            )
-            size = pkt.total_bytes(payload).astype(jnp.int64)
-            n = n.replace(
-                rx_rem=jnp.where(have & ~bootstrap, n.rx_rem - size, n.rx_rem)
-            )
+            with jax.named_scope(ROUTER_SCOPE):
+                r = state.subs[codel.SUB]
+                r, have, payload, src = codel.dequeue(
+                    r, now, want, aqm=self.router_aqm
+                )
+            with jax.named_scope(NIC_SCOPE):
+                size = pkt.total_bytes(payload).astype(jnp.int64)
+                n = n.replace(
+                    rx_rem=jnp.where(have & ~bootstrap, n.rx_rem - size,
+                                     n.rx_rem)
+                )
             state = state.with_sub(codel.SUB, r).with_sub(nic.SUB, n)
 
             state = self._deliver_local(
@@ -463,8 +497,8 @@ class NetStack:
             )
             n = state.subs[nic.SUB]
 
-        r = state.subs[codel.SUB]
-        still = codel.nonempty(r)
+        with jax.named_scope(ROUTER_SCOPE):
+            still = codel.nonempty(state.subs[codel.SUB])
         need = mask & still
         can_next = bootstrap | (n.rx_rem >= pkt.MTU)
         t_next = jnp.where(can_next, now, nic.next_refill_time(now))

@@ -410,7 +410,7 @@ def snapshot_device(sim, reg: MetricsRegistry) -> None:
     router = subs.get("router")
     if router is not None:
         _sub_counter(reg, jax.device_get(router), "net.router",
-                     ("codel_dropped",))
+                     ("codel_dropped", "deep_high_water"))
     tcp = subs.get("tcp")
     if tcp is not None:
         _sub_counter(reg, jax.device_get(tcp), "net.tcp",

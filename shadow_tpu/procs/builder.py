@@ -38,6 +38,12 @@ def build_process_driver(
     bad = [h.name for h in cfg.hosts if not h.processes]
     if bad:
         raise ProcessBuildError(f"hosts without processes: {bad}")
+    deep = [h.name for h in cfg.hosts if h.router_queue_slots is not None]
+    if deep:
+        raise ProcessBuildError(
+            f"hosts {deep}: the managed-process plane has no deep router "
+            f"ring (hosts.<group>.router_queue_slots)"
+        )
     hosts = cfg.hosts
 
     topo = Topology.from_gml(cfg.graph_gml(), cfg.network.use_shortest_path)

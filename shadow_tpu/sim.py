@@ -15,7 +15,13 @@ from shadow_tpu.core import simtime, units
 from shadow_tpu.core.config import Config, load_config
 from shadow_tpu.core.engine import Simulation
 from shadow_tpu.core.state import NetParams
-from shadow_tpu.net.apps import PholdApp, TcpBulkApp, UdpEchoApp, UdpFloodApp
+from shadow_tpu.net.apps import (
+    PholdApp,
+    TcpBulkApp,
+    UdpEchoApp,
+    UdpFloodApp,
+    locality_targets,
+)
 from shadow_tpu.net.stack import NetStack
 from shadow_tpu.routing.dns import Dns
 from shadow_tpu.routing.topology import BakedPaths, Topology
@@ -78,6 +84,34 @@ def _qdisc_discipline(cfg: Config, H: int):
     return PifoDiscipline(**kw)
 
 
+def _start_offsets(clients: list[int], target, H: int, spread: int,
+                   seed: int) -> np.ndarray:
+    """[H] ns after start_time at which each host starts: client j (in
+    host order) of the n clients at slot_j * spread // n. `slot` is a
+    permutation of 0..n-1 drawn from the seed (numpy's default_rng of seed
+    mod 2^64) and stratified by target: for each target in order, its m
+    clients (in host order) draw keys (rng.permutation(m) + rng.random(m))
+    / m, and slot is a client's rank by key. Each target's clients so take
+    evenly spread slots and the targets interleave in a seeded order: a
+    seed changes which client sends when, never the load a target sees in
+    any stretch of the interval. Spread 0 starts every client at
+    start_time."""
+    out = np.zeros(H, np.int64)
+    n = len(clients)
+    if spread and n:
+        rng = np.random.default_rng(seed % 2**64)
+        clients = np.asarray(clients)
+        tgt = np.asarray(target)[clients]
+        key = np.empty(n)
+        for t in np.unique(tgt):
+            mine = np.flatnonzero(tgt == t)
+            m = len(mine)
+            key[mine] = (rng.permutation(m) + rng.random(m)) / m
+        slot = np.argsort(np.argsort(key, kind="stable"), kind="stable")
+        out[clients] = slot.astype(np.int64) * spread // n
+    return out
+
+
 def build_simulation(source) -> Simulation:
     """Build from a Config, YAML path/string, or dict."""
     cfg = source if isinstance(source, Config) else load_config(source)
@@ -136,6 +170,24 @@ def build_simulation(source) -> Simulation:
     payload_words = 12  # net/packet.py layout; pure-PDES apps shrink it
     H = len(cfg.hosts)
     app_names = {h.app_model for h in cfg.hosts if h.app_model}
+    # hosts whose group gives them a router ring of their own depth
+    deep = {i: h.router_queue_slots for i, h in enumerate(cfg.hosts)
+            if h.router_queue_slots is not None}
+    if deep and not app_names & {"udp_flood", "udp_echo", "tcp_bulk"}:
+        raise BuildError(
+            "hosts.<group>.router_queue_slots needs a network-stack app "
+            "model (udp_flood, udp_echo, tcp_bulk)"
+        )
+    if len(set(deep.values())) > 1:
+        raise BuildError(
+            "every host group that sets router_queue_slots must set the "
+            f"same depth, got {sorted(set(deep.values()))}"
+        )
+    if deep and cfg.experimental.num_shards > 1:
+        raise BuildError(
+            "the islands and mesh layouts do not carry the deep router "
+            "ring: hosts.<group>.router_queue_slots needs num_shards 1"
+        )
     if "phold" in app_names:
         phold_hosts = [h for h in cfg.hosts if h.app_model == "phold"]
         if len(phold_hosts) != H:
@@ -220,22 +272,32 @@ def build_simulation(source) -> Simulation:
             with_tcp=(name == "tcp_bulk"),
             discipline=_qdisc_discipline(cfg, H),
             payload_words=payload_words,
+            deep_hosts=sorted(deep),
+            deep_queue_slots=next(iter(deep.values()), 0),
         )
         interval = units.parse_time_ns(
             client_opts.get("interval", "100 ms"), default_unit="ms"
         )
         start = units.parse_time_ns(client_opts.get("start_time", 1))
+        spread = units.parse_time_ns(client_opts.get("start_spread", 0))
+        if spread and name != "udp_flood":
+            raise BuildError(f"{name} has no start_spread option")
         stop_send = (
             units.parse_time_ns(client_opts["runtime"]) + start
             if "runtime" in client_opts
             else None
         )
         if name == "udp_flood":
+            local_span = int(client_opts.get("local_span", 0))
             app = UdpFloodApp(
                 H, servers, interval,
                 size_bytes=int(client_opts.get("size", 1024)),
                 start_time=start, stop_sending=stop_send,
-                local_span=int(client_opts.get("local_span", 0)),
+                local_span=local_span,
+                start_offsets=_start_offsets(
+                    [i for i, r in roles.items() if r == "client"],
+                    locality_targets(H, servers, local_span), H, spread,
+                    cfg.general.seed),
             )
         elif name == "tcp_bulk":
             app = TcpBulkApp(
